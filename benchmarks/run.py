@@ -337,6 +337,7 @@ def bench_sharded_spmm():
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.launch.mesh import make_mesh
     from repro.parallel.sharding import ShardingPolicy
     from repro.parallel.spmm import sharded_execute_planned
     from repro.runtime import KernelRequest, get_backend, plan_operand
@@ -360,7 +361,7 @@ def bench_sharded_spmm():
     b = jnp.asarray(rng.normal(size=(k, n)).astype(np.float32))
 
     plan = plan_operand(a, bm=bm, bk=bk)
-    policy = ShardingPolicy(mesh=jax.make_mesh((8,), ("data",)))
+    policy = ShardingPolicy(mesh=make_mesh((8,), ("data",)))
     be = get_backend("interpret")
 
     # exact per-device grid steps from the plan metadata (host-side)

@@ -30,9 +30,7 @@ _SEP = "/"
 
 
 def _flatten(tree):
-    # jax.tree.flatten_with_path only exists on newer jax releases
-    _fwp = getattr(jax.tree, "flatten_with_path", None) or jax.tree_util.tree_flatten_with_path
-    flat = _fwp(tree)[0]
+    flat = jax.tree.flatten_with_path(tree)[0]
 
     def name(path):
         parts = []

@@ -140,12 +140,6 @@ def _check_compact_grid(value) -> CompactGrid:
     )
 
 
-def _compiler_params(**kw):
-    # jax renamed TPUCompilerParams -> CompilerParams across releases
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kw)
-
-
 def _mask_to_plan_argsort(nonzero: jax.Array):
     """Legacy argsort-based compaction (v1) — kept as the equality oracle
     for :func:`_mask_to_plan` and the ``plan_cache_micro`` planning-time
@@ -447,6 +441,20 @@ def _epilogue(acc, bias_blk, res_blk, activation: str):
     return out
 
 
+#: lanes per emitted-mask block.  The chip only tiles blocks whose last two
+#: dims are (8, 128)-aligned or span the array, so each ``(m, n)`` flag is
+#: stored as one ``[1, 128]`` int32 row of a ``[Mb, 1, Nb * 128]`` buffer
+#: and the wrapper reads every 128th lane back into the ``int8 [Mb, Nb]``
+#: mask callers see.
+_MASK_LANES = 128
+
+
+def _store_mask(mask_ref, out):
+    """Store the block-nonzero flag of the fp32 epilogue value ``out``."""
+    flag = jnp.max(jnp.where(out != 0, 1, 0).astype(jnp.int32))
+    mask_ref[...] = jnp.full(mask_ref.shape, flag, jnp.int32)
+
+
 def _fused_kernel(nnz_ref, idx_ref, a_ref, b_ref, *rest,
                   activation: str, has_bias: bool, has_residual: bool):
     rest = list(rest)
@@ -474,7 +482,7 @@ def _fused_kernel(nnz_ref, idx_ref, a_ref, b_ref, *rest,
             res_ref[...].astype(jnp.float32) if has_residual else None,
             activation,
         )
-        mask_ref[0, 0] = jnp.any(out != 0).astype(jnp.int8)
+        _store_mask(mask_ref, out)
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -530,7 +538,7 @@ def _ragged_fused_kernel(nnz_ref, rs_ref, wr_ref, wk_ref, a_ref, b_ref, *rest,
             res_ref[...].astype(jnp.float32) if has_residual else None,
             activation,
         )
-        mask_ref[0, 0] = jnp.any(out != 0).astype(jnp.int8)
+        _store_mask(mask_ref, out)
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -660,7 +668,7 @@ def tensordash_matmul_planned(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=_compiler_params(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )(*operands)
 
@@ -741,13 +749,17 @@ def tensordash_matmul_fused(
         in_specs.append(pl.BlockSpec((bm, bn), o_map))
         operands.append(residual)
 
+    def mask_map(*args):  # the output block (m_i, n_i), lane-dense
+        m_i, n_i = o_map(*args)
+        return (m_i, 0, n_i)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_prefetch,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((bm, bn), o_map),
-            pl.BlockSpec((1, 1), o_map),  # mask block (m_i, n_i), same map
+            pl.BlockSpec((pl.squeezed, 1, _MASK_LANES), mask_map),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )
@@ -757,16 +769,17 @@ def tensordash_matmul_fused(
         has_bias=bias is not None,
         has_residual=residual is not None,
     )
-    return pl.pallas_call(
+    out, mask = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((m, n), out_dtype),
-            jax.ShapeDtypeStruct((mb, nb), jnp.int8),
+            jax.ShapeDtypeStruct((mb, 1, nb * _MASK_LANES), jnp.int32),
         ],
-        compiler_params=_compiler_params(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
     )(*operands)
+    return out, mask[:, 0, ::_MASK_LANES].astype(jnp.int8)
 
 
 def tensordash_matmul(

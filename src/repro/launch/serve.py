@@ -5,7 +5,9 @@ throughput.
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --smoke \
         --requests 16 --slots 8 --new 8 --backend interpret --rate 0
 
-``--rate`` requests/second shapes the arrival stream (0 = all requests
+Without ``--smoke`` the config runs at its published widths, tensor
+parallel on a ``model`` mesh over the devices present.  ``--rate``
+requests/second shapes the arrival stream (0 = all requests
 arrive at t=0, a pure throughput run); prompt lengths and decode budgets are
 jittered per request so the engine's slot backfill actually exercises.  One
 ``repro.runtime.Runtime`` carries the whole execution policy (kernel
@@ -27,14 +29,13 @@ import argparse
 import sys
 import time
 
-import jax
 import numpy as np
 
 from repro import runtime as rtm
 from repro.configs import get_config, reduce_config
-from repro.launch.mesh import make_production_mesh
-from repro.models import model as M
-from repro.models.common import init_params
+from repro.launch.mesh import (
+    enable_compile_cache, init_sharded_params, make_local_mesh,
+)
 from repro.parallel.sharding import ShardingPolicy
 from repro.resilience import FaultPlan, ResilienceLog, capture_warnings
 from repro.resilience import faults as rfaults
@@ -51,6 +52,59 @@ def _pct(xs, q):
 
 def _ms(x):
     return f"{x * 1e3:.0f}ms" if x is not None else "n/a"
+
+
+def make_traffic(rng, vocab_size: int, *, requests: int, prompt_len: int,
+                 new: int, rate: float):
+    """``(prompts, budgets, arrivals)`` for one replay.  Lengths are
+    jittered over ``[prompt_len // 2, prompt_len]`` and ``[new // 2, new]``
+    so slots finish at different times and backfill runs; arrivals are
+    Poisson at ``rate`` requests/second (all at 0 when ``rate <= 0``),
+    relative to the start of the replay."""
+    plens = rng.integers(max(prompt_len // 2, 1), prompt_len + 1, size=requests)
+    budgets = rng.integers(max(new // 2, 1), new + 1, size=requests)
+    prompts = [rng.integers(0, vocab_size, size=int(s)).astype(np.int32)
+               for s in plens]
+    arrivals = (np.zeros(requests) if rate <= 0
+                else np.cumsum(rng.exponential(1.0 / rate, size=requests)))
+    return prompts, budgets, arrivals
+
+
+def replay(eng: ServeEngine, prompts, budgets, arrivals, *,
+           ttl: float | None = None) -> float:
+    """Submit each request at its arrival and step the engine until every
+    request is done; returns the wall seconds.  Arrivals are scheduled on
+    the engine clock, so latency percentiles measure from the modeled
+    arrival — queueing delay (a request waiting out an in-flight decode
+    chunk) is charged to the request, not hidden."""
+    arrivals = np.asarray(arrivals) + eng.now()
+    t_start = time.monotonic()
+    submitted = 0
+    while submitted < len(prompts) or eng.sched.has_work:
+        now = eng.now()
+        while submitted < len(prompts) and arrivals[submitted] <= now:
+            try:
+                eng.submit(prompts[submitted],
+                           max_new=int(budgets[submitted]),
+                           arrival=float(arrivals[submitted]), ttl=ttl)
+                submitted += 1
+            except QueueFull:
+                break  # drain a chunk below, then retry this submit
+        if not eng.sched.has_work:
+            # idle before the next arrival: wait it out
+            time.sleep(min(max(arrivals[submitted] - now, 0.0), 0.05))
+            continue
+        eng.step()
+    return time.monotonic() - t_start
+
+
+def finish_reasons(eng: ServeEngine) -> dict[str, int]:
+    """Count of every submitted request's finish reason."""
+    reasons: dict[str, int] = {}
+    for r in eng._requests.values():
+        key = r.finish_reason or "unfinished"
+        reasons[key] = reasons.get(key, 0) + 1
+    return reasons
 
 
 def main(argv=None) -> None:
@@ -90,29 +144,22 @@ def main(argv=None) -> None:
                     help="disable the in-graph non-finite logits watchdog")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
-    mesh = None
     if args.smoke:
         cfg = reduce_config(cfg)
-    else:
-        mesh = make_production_mesh()
     geom = dict(zip(("bm", "bk", "bn"), args.block)) if args.block else {}
-    policy = ShardingPolicy(mesh=mesh)
+    policy = ShardingPolicy(mesh=make_local_mesh())
     rt = rtm.Runtime(backend=args.backend, sharding=policy,
                      geometry=args.geometry, **geom)
     rt.kernel.check_platform()  # fail fast (e.g. pallas on CPU)
 
-    params = init_params(M.param_specs(cfg), jax.random.PRNGKey(0))
-    rng = np.random.default_rng(args.seed)
-    # jitter lengths so slots finish at different times and backfill runs
-    plens = rng.integers(max(args.prompt_len // 2, 1), args.prompt_len + 1,
-                         size=args.requests)
-    budgets = rng.integers(max(args.new // 2, 1), args.new + 1,
-                           size=args.requests)
-    prompts = [rng.integers(0, cfg.vocab_size, size=int(s)).astype(np.int32)
-               for s in plens]
-    arrivals = (np.zeros(args.requests) if args.rate <= 0
-                else np.cumsum(rng.exponential(1.0 / args.rate, size=args.requests)))
+    params = init_sharded_params(cfg, policy)
+    prompts, budgets, arrivals = make_traffic(
+        np.random.default_rng(args.seed), cfg.vocab_size,
+        requests=args.requests, prompt_len=args.prompt_len, new=args.new,
+        rate=args.rate,
+    )
 
     log = ResilienceLog()
     fp = FaultPlan.parse(args.inject_faults, seed=args.fault_seed)
@@ -125,30 +172,8 @@ def main(argv=None) -> None:
         watchdog=not args.no_watchdog, fault_plan=fp if fp else None,
         log=log,
     )
-    # arrivals are scheduled on the engine clock, so latency percentiles
-    # measure from the modeled arrival — queueing delay (a request waiting
-    # out an in-flight decode chunk) is charged to the request, not hidden
-    arrivals = arrivals + eng.now()
-    t_start = time.monotonic()
-    submitted = 0
     with rlog.use_log(log), rfaults.inject(fp), capture_warnings(log):
-        while submitted < args.requests or eng.sched.has_work:
-            now = eng.now()
-            while submitted < args.requests and arrivals[submitted] <= now:
-                try:
-                    eng.submit(prompts[submitted],
-                               max_new=int(budgets[submitted]),
-                               arrival=float(arrivals[submitted]),
-                               ttl=args.ttl)
-                    submitted += 1
-                except QueueFull:
-                    break  # drain a chunk below, then retry this submit
-            if not eng.sched.has_work:
-                # idle before the next arrival: wait it out
-                time.sleep(min(max(arrivals[submitted] - now, 0.0), 0.05))
-                continue
-            eng.step()
-    dt = time.monotonic() - t_start
+        dt = replay(eng, prompts, budgets, arrivals, ttl=args.ttl)
 
     reqs = list(eng._requests.values())
     ok = [r for r in reqs if r.ok]
@@ -163,13 +188,8 @@ def main(argv=None) -> None:
           f"{st['decode_traces']}x, {st['chunks_run']} chunks")
     print(f"latency  ttft p50={_ms(_pct(ttft,50))} p95={_ms(_pct(ttft,95))}"
           f"   e2e p50={_ms(_pct(e2e,50))} p95={_ms(_pct(e2e,95))}")
-    reasons: dict[str, int] = {}
-    for r in reqs:
-        reasons[r.finish_reason or "unfinished"] = (
-            reasons.get(r.finish_reason or "unfinished", 0) + 1
-        )
     print("finish reasons: " + ", ".join(
-        f"{k}={v}" for k, v in sorted(reasons.items())))
+        f"{k}={v}" for k, v in sorted(finish_reasons(eng).items())))
     print(f"plan cache: {pc['hits']} hits / {pc['misses']} misses / "
           f"{pc['traced']} traced-in-program")
     # per-plan skew report: total_work is the exact v3 ragged-grid step

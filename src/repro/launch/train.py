@@ -3,16 +3,18 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --smoke \
         --steps 20 --ckpt-dir /tmp/ckpt
 
-On real hardware this runs the full config on the production mesh (one
-process per host, jax.distributed); on this CPU container ``--smoke`` runs
-the reduced config end-to-end with the identical code path: mesh, sharded
-params, checkpointing, preemption guard, straggler deadline, TensorDash
-sparsity projection.
+Without ``--smoke`` this runs the config at its published widths on a
+``data`` mesh over the devices present (batch parallel plus FSDP); ``--smoke``
+runs the reduced config (CPU) through the identical code path: mesh,
+sharded params, checkpointing, preemption guard, straggler deadline,
+TensorDash sparsity projection.  The step donates ``params`` and the
+optimizer state, so each step updates them in place.
 
 Resilience: the step is non-finite-guarded (``make_train_step(
 guard_nonfinite=True)``) — a NaN/Inf loss or gradient skips the update,
 backs off exponentially, and after ``--max-faults`` *consecutive* faulted
-steps checkpoints-before-abort (exit code 3).  ``--inject-faults`` replays
+steps checkpoints-before-abort (exit code 3); a step past
+``--step-deadline`` checkpoints and exits with code 4.  ``--inject-faults`` replays
 a seeded :class:`repro.resilience.FaultPlan` (``nan_loss@3;step_stall@5:
 secs=1`` ...) through the exact production loop, and every degradation —
 skip-step, straggler abort, preemption save, corrupt-checkpoint skip — is
@@ -25,9 +27,12 @@ import dataclasses
 import signal
 import sys
 import time
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
 from repro import runtime as rtm
 from repro.checkpoint.manager import PreemptionGuard, restore_latest, save
 from repro.resilience import FaultPlan, ResilienceLog, capture_warnings
@@ -35,9 +40,9 @@ from repro.resilience import faults as rfaults
 from repro.resilience import log as rlog
 from repro.configs import get_config, reduce_config
 from repro.data.pipeline import SyntheticLM
-from repro.launch.mesh import make_local_mesh, make_production_mesh
-from repro.models import model as M
-from repro.models.common import init_params
+from repro.launch.mesh import (
+    enable_compile_cache, init_sharded_params, make_local_mesh,
+)
 from repro.optim.adamw import OptConfig, init_opt_state
 from repro.parallel.sharding import ShardingPolicy
 from repro.train.step import make_train_step
@@ -71,11 +76,21 @@ def parse_dynamic_sparsity(spec: str) -> dict:
     return kw
 
 
-def main(argv=None) -> None:
+@dataclasses.dataclass
+class TrainRun:
+    """What :func:`main` ran: the host copy of every executed step's
+    metrics (plus its wall ``seconds``), and ``lower()``, which lowers the
+    step program again as its last step ran (same arguments, mesh and
+    runtime) without running it."""
+
+    history: list
+    lower: Callable[[], Any]
+
+
+def main(argv=None) -> TrainRun:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
-    ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--seq", type=int, default=32)
     ap.add_argument("--batch", type=int, default=8)
@@ -117,12 +132,11 @@ def main(argv=None) -> None:
                          "loss/grads")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = reduce_config(cfg)
-        mesh = make_local_mesh()
-    else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+    mesh = make_local_mesh(data=True)
     cfg = dataclasses.replace(cfg, remat=not args.smoke)
     geom = {k: v for k, v in (("bm", args.bm), ("bk", args.bk), ("bn", args.bn)) if v}
     if args.smoke and not geom and (
@@ -140,13 +154,9 @@ def main(argv=None) -> None:
     fp = FaultPlan.parse(args.inject_faults, seed=args.fault_seed)
     guard_nonfinite = not args.no_nonfinite_guard
 
-    specs = M.param_specs(cfg)
-    shardings = policy.param_shardings(specs)
     with mesh, rtm.use(rt), rlog.use_log(log), rfaults.inject(fp), \
             capture_warnings(log):
-        params = jax.jit(
-            lambda k: init_params(specs, k), out_shardings=shardings
-        )(jax.random.PRNGKey(0))
+        params = init_sharded_params(cfg, policy)
         opt = init_opt_state(params)
         data = SyntheticLM(cfg.vocab_size, args.seq, args.batch)
         ocfg = OptConfig(total_steps=max(args.steps, 100))
@@ -169,7 +179,7 @@ def main(argv=None) -> None:
             cfg, ocfg, microbatches=args.microbatches,
             sparsity_taps=args.sparsity_taps, dynamic_sparsity=ctrl,
             guard_nonfinite=guard_nonfinite,
-        ))
+        ), donate_argnums=(0, 1))
         guard = PreemptionGuard()
 
         start = 0
@@ -182,6 +192,14 @@ def main(argv=None) -> None:
                 print(f"resumed at step {s}")
 
         consecutive_faults = 0
+        history: list = []
+        step_args: tuple = ()
+        kw: dict = {}
+
+        def lower():  # reads the last step's step_args / kw when called
+            with mesh, rtm.use(rt):
+                return step_fn.lower(*step_args, **kw)
+
         for i in range(start, args.steps):
             for _ in fp.fires("preempt", i):
                 signal.raise_signal(signal.SIGTERM)
@@ -190,13 +208,15 @@ def main(argv=None) -> None:
             kw = {}
             if guard_nonfinite:
                 kw["poison"] = jnp.int32(rfaults.train_poison(fp, i))
+            step_args = (params, opt, data.batch_at(i))
             if ctrl is not None:
-                params, opt, m = step_fn(params, opt, data.batch_at(i),
-                                         masks, **kw)
-            else:
-                params, opt, m = step_fn(params, opt, data.batch_at(i), **kw)
+                step_args += (masks,)
+            params, opt, m = step_fn(*step_args, **kw)
+            step_args = (params, opt) + step_args[2:]
             m = jax.device_get(m)
             dt = time.time() - t0
+            history.append({"step": i, "seconds": dt, **{
+                k: float(v) for k, v in m.items() if np.ndim(v) == 0}})
             if guard_nonfinite and int(m.get("nonfinite", 0)):
                 consecutive_faults += 1
                 log.record("nonfinite", "train.step", "skip-step",
@@ -237,14 +257,12 @@ def main(argv=None) -> None:
                 if args.ckpt_dir:
                     save(args.ckpt_dir, i + 1, {"params": params, "opt": opt})
                 print(log.summary())
-                return
+                sys.exit(4)
             if (i + 1) % 5 == 0 or i == start:
                 line = f"step {i+1:5d} loss {float(m['loss']):.4f} gnorm {float(m['grad_norm']):.2f} {dt:.2f}s"
                 if ctrl is not None:
                     line += f" Wdens={float(m['dst_density']):.2f}"
                 if args.sparsity_taps:
-                    import numpy as np
-
                     from repro.train.step import modeled_speedup
 
                     sim = modeled_speedup(m, cfg, max_t=64, sample_groups=1)
@@ -262,7 +280,7 @@ def main(argv=None) -> None:
                                step=i)
                     print("preemption: saved, exiting")
                     print(log.summary())
-                    return
+                    return TrainRun(history, lower)
     # per-device balance report: how evenly each cached plan's ragged-grid
     # work would deal across the policy's row-parallel shards
     n_shards = policy.spmm_axes("M")[1]
@@ -276,6 +294,7 @@ def main(argv=None) -> None:
     if len(log):
         print(log.summary())
     print("done")
+    return TrainRun(history, lower)
 
 
 if __name__ == "__main__":

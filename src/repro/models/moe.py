@@ -228,8 +228,6 @@ def moe_ffn(params, cfg: MoEConfig, x, *, mesh=None, seq_sharded: bool = True):
         y = _moe_local(cfg, {k: v for k, v in params.items() if k != "shared"}, x.reshape(-1, d))
         return y.reshape(b, s, d) + shared
 
-    from jax.experimental.shard_map import shard_map  # local import: heavy
-
     axes = mesh.axis_names
     dp = tuple(a for a in axes if a in ("pod", "data"))
     seq_ax = "model" if (seq_sharded and s % mesh.shape["model"] == 0 and s > 1) else None
@@ -249,11 +247,11 @@ def moe_ffn(params, cfg: MoEConfig, x, *, mesh=None, seq_sharded: bool = True):
         y = body(p, xl.reshape(t_local, d))
         return y.reshape(xl.shape)
 
-    y = shard_map(
+    y = jax.shard_map(
         flat_body,
         mesh=mesh,
         in_specs=(w_specs, x_spec),
         out_specs=x_spec,
-        check_rep=False,
+        check_vma=False,
     )({k: params[k] for k in w_specs}, x)
     return y + shared

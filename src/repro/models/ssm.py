@@ -120,10 +120,12 @@ def ssd_chunked(x, dt, a_log, b_in, c_in, *, chunk: int, init_state=None):
     c_c = ch(c_in.astype(jnp.float32))  # [B,nc,Q,N]
     cum = jnp.cumsum(dta_c, axis=2)  # [B,nc,Q,H]
 
-    # intra-chunk (diagonal blocks): L[i,j] = exp(cum_i - cum_j), i >= j
+    # intra-chunk (diagonal blocks): L[i,j] = exp(cum_i - cum_j), i >= j.
+    # Masked before the exp: above the diagonal cum_i - cum_j > 0 grows with
+    # the chunk and overflows, and the backward's 0 * inf would be NaN.
     li = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # [B,nc,Q,Q,H]
     tri = jnp.tril(jnp.ones((q, q), bool))
-    l_mat = jnp.where(tri[None, None, :, :, None], jnp.exp(li), 0.0)
+    l_mat = jnp.exp(jnp.where(tri[None, None, :, :, None], li, -jnp.inf))
     cb = jnp.einsum("bcin,bcjn->bcij", c_c, b_c)  # [B,nc,Q,Q]
     y_diag = jnp.einsum("bcij,bcijh,bcjhp->bcihp", cb, l_mat, x_c)
 

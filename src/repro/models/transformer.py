@@ -16,6 +16,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro import runtime as rtm
 from repro.configs.base import ModelConfig
@@ -144,7 +145,8 @@ def mlp_fwd(params, cfg: ModelConfig, x, taps: dict | None = None, mesh=None, rt
             h2 = g * (x2 @ params["w_up"])
             if taps is not None:
                 taps["ffn_act"] = sps.measure(h2.reshape(*lead, -1))
-            plan_h = rt.plan_for_fused_output(gmask, h2, params["w_down"])
+            plan_h = rt.plan_for_fused_output(gmask, h2, params["w_down"],
+                                              k=x2.shape[1])
             return rt.matmul(h2, params["w_down"], plan=plan_h).reshape(*lead, -1)
         h = act(x @ params["w_gate"]) * (x @ params["w_up"])
     else:
@@ -176,15 +178,30 @@ def head_matmul(cfg: ModelConfig, h, lm_head):
     single dense vocabulary row would drag every row back to dense cost.
     The cached plan carries its CSR work queue, so decode steps hand the
     kernel a precomputed schedule with zero planning dispatches.
+
+    XLA cannot partition a Pallas kernel, so on a mesh of several devices
+    the head runs under ``shard_map``: each device takes its own tokens
+    (split over the policy's data axes when they divide the token count)
+    against the whole head, and the head's gradient is summed over them.
     """
     del cfg
     rt = rtm.resolve()
     b, s, d = h.shape
-    if rt.wants_sparse:
-        h2 = h.reshape(b * s, d)
-        out = rt.matmul(h2, lm_head, plan_key=("lm_head", id(lm_head)), side="B")
-        return out.reshape(b, s, -1)
-    return h @ lm_head
+    if not rt.wants_sparse:
+        return h @ lm_head
+
+    def head(h2, w):
+        return rt.matmul(h2, w, plan_key=("lm_head", id(lm_head)), side="B")
+
+    mesh = rt.mesh
+    if mesh is not None and mesh.size > 1:
+        names, n = rt.sharding.spmm_axes("M")
+        tok = names if names and (b * s) % n == 0 else None
+        head = jax.shard_map(
+            head, mesh=mesh, in_specs=(P(tok, None), P()),
+            out_specs=P(tok, None), check_vma=False,
+        )
+    return head(h.reshape(b * s, d), lm_head).reshape(b, s, -1)
 
 
 def block_specs(cfg: ModelConfig, *, moe: bool) -> dict:

@@ -36,7 +36,9 @@ class OptState(NamedTuple):
 
 
 def init_opt_state(params) -> OptState:
-    zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
+    # zeros_like keeps each parameter's sharding: the moments are born
+    # sharded like their parameters, never whole on one device
+    zeros = lambda p: jnp.zeros_like(p, dtype=jnp.float32)
     return OptState(
         step=jnp.zeros((), jnp.int32),
         m=jax.tree.map(zeros, params),

@@ -46,7 +46,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.tensordash_spmm import plan_from_mask_csr, plan_workqueue
@@ -145,9 +144,9 @@ def _shard_m(be, req: KernelRequest, mesh, names, balance: bool, fused: bool):
         )
         return be.execute_fused(req_l) if fused else be.execute_planned(req_l)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh, in_specs=tuple(specs), out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(*ops)
     if not fused:
         return _take_block_rows(out, inv, req.bm) if inv is not None else out
@@ -190,9 +189,9 @@ def _shard_n(be, req: KernelRequest, mesh, names, fused: bool):
         )
         return be.execute_fused(req_l) if fused else be.execute_planned(req_l)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=tuple(specs), out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(*ops)
 
 
@@ -212,10 +211,10 @@ def _shard_k(be, req: KernelRequest, mesh, names):
         ))
         return jax.lax.psum(part, ax)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, ax), P(None, ax), P(ax, None)),
-        out_specs=P(None, None), check_rep=False,
+        out_specs=P(None, None), check_vma=False,
     )(mask, req.a, req.b)
     return out.astype(req.out_dtype or req.a.dtype)
 

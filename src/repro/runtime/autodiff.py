@@ -47,7 +47,7 @@ from repro.kernels.tensordash_spmm import (
     plan_from_mask_csr,
     transpose_plan_csr,
 )
-from repro.runtime.plan import PlanCache, SparsityPlan, _fit_block
+from repro.runtime.plan import PlanCache, SparsityPlan, _tile_block
 
 __all__ = [
     "PlannedVJP",
@@ -124,15 +124,17 @@ class PlannedVJP:
         wants a different lane width and grid family than the forward.
         Only those two knobs are free: ``bm/bk`` are pinned by the backward
         plan's geometry (a metadata transform of the forward plan), which
-        keeps the tuned backward bit-identical to the default one.  Returns
-        ``(bn, None)`` — the context defaults — when no DB rides along or
-        the cell is unmeasured."""
+        keeps the tuned backward bit-identical to the default one.  The
+        operands arrive padded, so a tuned lane width that would need more
+        padding keeps the default.  Returns ``(bn, None)`` — the context
+        defaults — when no DB rides along or the cell is unmeasured."""
         if self.db is None:
             return bn, None
         pol = self.db.resolve(op=op, m=m, k=k, n=n, dtype=dtype)
         if pol is None:
             return bn, None
-        return _fit_block(pol.bn, n), pol.compact_grid
+        tuned = _tile_block(pol.bn, n)
+        return (tuned if n % tuned == 0 else bn), pol.compact_grid
 
 
 def _is_traced(x) -> bool:

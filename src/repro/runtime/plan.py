@@ -23,6 +23,7 @@ import dataclasses
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
@@ -38,18 +39,58 @@ __all__ = [
 ]
 
 
+#: the TPU's lane width: a kernel block dim must be a multiple of it (the
+#: sublane dim of 8 divides it) or span the whole array dim
+LANE = 128
+
+
 def _fit_block(block: int, dim: int) -> int:
     """Largest divisor of ``dim`` that is <= ``block`` (always >= 1).
 
-    The one geometry-clamping primitive: ``Runtime.fit``/``Runtime.lane``
-    and the autodiff backward products all fit tuned or policy block sizes
-    to operand dims through this, so planned execution never needs a dense
-    escape hatch for small or odd operands.
+    The clamp for blockings of a fixed tensor that cannot be padded: the
+    dynamic-sparsity weight masks, the tuner's candidate lattice and the
+    replanning of a corrupt plan.  Execution geometry goes through
+    :func:`_tile_block` instead.
     """
     b = max(1, min(block, dim))
     while dim % b:
         b -= 1
     return b
+
+
+def _tile_block(block: int, dim: int) -> int:
+    """Chip-legal tile for ``dim`` at the target ``block``.
+
+    The whole dim when it fits the target; otherwise the largest multiple
+    of ``align = min(block, LANE)`` that is <= ``block`` and divides ``dim``
+    rounded up to ``align``.  A target of at least :data:`LANE` therefore
+    always yields a multiple of 128 or the full dim, which the TPU compiler
+    accepts in either position of a 2-D block.  Where the tile does not
+    divide ``dim`` the executor zero-pads the operand to a tile multiple
+    (:func:`_round_up`): the padding adds only all-zero blocks, which the
+    plan skips, so results are those of the unpadded product.  Targets
+    under :data:`LANE` are interpreter-only test geometries.
+    """
+    if dim <= block:
+        return dim
+    align = min(block, LANE)
+    padded = _round_up(dim, align)
+    b = block // align * align
+    while padded % b:
+        b -= align
+    return b
+
+
+def _round_up(dim: int, block: int) -> int:
+    return -(-dim // block) * block
+
+
+def _pad_to(x, shape):
+    """``x`` zero-padded at the end of each dim up to ``shape`` (``x``
+    itself when it already has that shape)."""
+    if tuple(x.shape) == tuple(shape):
+        return x
+    return jnp.pad(x, [(0, t - s) for s, t in zip(x.shape, shape)])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,12 +239,13 @@ def plan_operand(a, bm: int, bk: int, *, side: str = "A") -> SparsityPlan:
 
     One fused dispatch builds the whole payload — compacted ``(nnz, idx)``
     plus the v3 work queue — so ragged execution never pays a second
-    planning pass."""
+    planning pass.  Dims the blocks do not divide are zero-padded first
+    (:func:`_tile_block`): the plan's ``shape`` is the padded shape, and
+    executors pad the operand to it."""
     from repro.kernels.tensordash_spmm import plan_blocks_csr  # local: keep import light
 
-    m, k = a.shape
-    if m % bm or k % bk:
-        raise ValueError(f"operand {a.shape} not divisible by block ({bm}, {bk})")
+    m, k = _round_up(a.shape[0], bm), _round_up(a.shape[1], bk)
+    a = _pad_to(a, (m, k))
     nnz, idx, row_starts, work_row, work_kblk = plan_blocks_csr(a, bm, bk)
     return SparsityPlan(
         nnz=nnz, idx=idx, bm=bm, bk=bk, shape=(m, k), dtype=a.dtype, side=side,
@@ -244,12 +286,11 @@ def plan_from_emitted_mask(mask, shape, dtype, *, bm: int, mask_bn: int,
 def dense_operand_plan(shape, dtype, *, bm: int, bk: int, side: str = "A") -> SparsityPlan:
     """The trivial all-effectual plan for a known-dense operand — metadata
     only (``nnz = Kb``, ``idx = arange``, closed-form work queue), skipping
-    the values pass a :func:`plan_operand` call would make."""
+    the values pass a :func:`plan_operand` call would make.  Like that
+    call, it covers ``shape`` rounded up to whole blocks."""
     from repro.kernels.tensordash_spmm import dense_plan_csr  # local: keep import light
 
-    m, k = shape
-    if m % bm or k % bk:
-        raise ValueError(f"operand {shape} not divisible by block ({bm}, {bk})")
+    m, k = _round_up(shape[0], bm), _round_up(shape[1], bk)
     nnz, idx, row_starts, work_row, work_kblk = dense_plan_csr(m // bm, k // bk)
     return SparsityPlan(
         nnz=nnz, idx=idx, bm=bm, bk=bk, shape=(m, k), dtype=dtype, side=side,

@@ -17,13 +17,13 @@ The PR-1 era entry points (``mode=`` kwargs on ``repro.kernels.ops``,
 factories) completed their one-release deprecation cycle and are gone; all
 code constructs a ``Runtime``.
 
-Block geometry is a *target*, not a contract: when an operand is smaller
-than (or indivisible by) ``bm/bk/bn``, planned execution auto-clamps each
-block dim to the largest divisor of the operand dim (:meth:`Runtime.fit`)
-instead of silently falling back to dense XLA.  Clamping never changes
-numerics — the planned executors are bit-exact across backends at any
-geometry — it only changes the block granularity at which all-zero work is
-skipped.
+Block geometry is a *target*, not a contract: planned execution fits each
+block dim to its operand dim as a chip-legal tile (:meth:`Runtime.fit`) —
+the whole dim when it is smaller than the target, else a multiple of 128 —
+and zero-pads an operand the tile does not divide, instead of silently
+falling back to dense XLA.  Neither changes numerics (padding adds only
+all-zero blocks, which the plan skips); both only change the block
+granularity at which all-zero work is skipped.
 """
 from __future__ import annotations
 
@@ -44,6 +44,9 @@ from repro.runtime.plan import (
     PlanCache,
     SparsityPlan,
     _fit_block,
+    _pad_to,
+    _round_up,
+    _tile_block,
     dense_operand_plan,
     plan_from_emitted_mask,
     plan_operand,
@@ -208,19 +211,20 @@ class Runtime:
         return self.plan_cache.get_or_build(key, a, bm, self.bk, side=side)
 
     def fit(self, a_shape, b_shape) -> "Runtime":
-        """This runtime with block geometry clamped to ``a @ b``'s shapes.
+        """This runtime with block geometry fitted to ``a @ b``'s shapes.
 
-        Each of ``bm/bk/bn`` is reduced to the largest divisor of the
-        corresponding operand dim, so planned execution never needs a dense
-        escape hatch for small or odd operands (e.g. a 3-token microbatch
-        under bm=128 plans with bm=3).  The plan cache handle is shared —
-        clamped geometry is part of every cache key, so fitted and unfitted
-        plans never collide.  On a real TPU, MXU-aligned shapes should still
-        be preferred; clamping preserves correctness, not peak throughput.
+        Each of ``bm/bk/bn`` becomes a chip-legal tile of the corresponding
+        operand dim (:func:`~repro.runtime.plan._tile_block`): the whole dim
+        when it is under the target (a 3-token microbatch under bm=128 plans
+        with bm=3), else a multiple of 128 — where none divides the dim the
+        execution methods zero-pad the operands to a tile multiple (a
+        50280-row vocabulary runs as 50304 rows of 128).  The plan cache
+        handle is shared — fitted geometry is part of every cache key, so
+        fitted and unfitted plans never collide.
         """
         m, k = a_shape
         n = b_shape[1]
-        bm, bk, bn = _fit_block(self.bm, m), _fit_block(self.bk, k), _fit_block(self.bn, n)
+        bm, bk, bn = _tile_block(self.bm, m), _tile_block(self.bk, k), _tile_block(self.bn, n)
         if (bm, bk, bn) == (self.bm, self.bk, self.bn):
             return self
         return self.replace(bm=bm, bk=bk, bn=bn)
@@ -232,11 +236,10 @@ class Runtime:
         second-guess its hand-set policy, forward or backward)."""
         return self.tuning_db if self.geometry == "auto" else None
 
-    def lane(self, dim: int, block: int | None = None) -> int:
-        """Fitted output-lane width: the largest divisor of ``dim`` that is
-        <= the target block (:attr:`bn` unless overridden) — the one
-        call-site clamp left now that :meth:`_resolved` owns geometry."""
-        return _fit_block(self.bn if block is None else block, dim)
+    def lane(self, dim: int) -> int:
+        """Fitted output-lane width: the chip-legal tile of ``dim`` at the
+        target :attr:`bn` (operands are padded to a multiple of it)."""
+        return _tile_block(self.bn, dim)
 
     def _policy(self, op: str, a_shape, b_shape, dtype, *, density=None):
         """The tuned policy for one call site, or ``None`` (explicit
@@ -250,17 +253,10 @@ class Runtime:
             density=density,
         )
 
-    def _resolved(self, op: str, a_shape, b_shape, dtype, *,
+    def _overlaid(self, op: str, a_shape, b_shape, dtype, *,
                   plan: SparsityPlan | None = None, density=None) -> "Runtime":
-        """THE geometry-resolution path every execution method funnels
-        through — replaces the old scattered per-call ``_fit_block``
-        hand-fits.  Resolve the tuned policy for ``op`` (``geometry="auto"``
-        only), overlay it on this runtime's defaults, then clamp to the
-        operand shapes.  With a caller-provided ``plan``, the plan's own
-        blocking governs ``bm/bk`` (changing them would reassociate the
-        block accumulation); only the lane width and grid family stay free
-        to tune — the same contract the backward products follow
-        (``PlannedVJP._bwd_policy``)."""
+        """This runtime with the tuned policy for ``op`` overlaid
+        (``geometry="auto"`` only), before fitting to the shapes."""
         pol = self._policy(op, a_shape, b_shape, dtype, density=density)
         rt = self
         if pol is not None:
@@ -271,6 +267,20 @@ class Runtime:
                                     compact_grid=pol.compact_grid)
             elif (pol.bn, pol.compact_grid) != (rt.bn, rt.compact_grid):
                 rt = rt.replace(bn=pol.bn, compact_grid=pol.compact_grid)
+        return rt
+
+    def _resolved(self, op: str, a_shape, b_shape, dtype, *,
+                  plan: SparsityPlan | None = None, density=None) -> "Runtime":
+        """THE geometry-resolution path every execution method funnels
+        through.  Resolve the tuned policy for ``op`` (``geometry="auto"``
+        only), overlay it on this runtime's defaults (:meth:`_overlaid`),
+        then fit to the operand shapes.  With a caller-provided ``plan``,
+        the plan's own blocking governs ``bm/bk`` (changing them would
+        reassociate the block accumulation); only the lane width and grid
+        family stay free to tune — the same contract the backward products
+        follow (``PlannedVJP._bwd_policy``)."""
+        rt = self._overlaid(op, a_shape, b_shape, dtype, plan=plan,
+                            density=density)
         return rt if plan is not None else rt.fit(a_shape, b_shape)
 
     def supports_matmul(self, a_shape, b_shape, *, side: str = "A") -> bool:
@@ -343,8 +353,9 @@ class Runtime:
         ``side="B"`` exploits (static, typically weight) sparsity of ``b``,
         executed through the same kernel as ``(b.T @ a.T).T``.  ``plan_key``
         routes planning through the keyed cache — the serving decode loop's
-        amortization path.  Block geometry auto-clamps to the operand shapes
-        (:meth:`fit`): there is no silent dense fallback for small operands.
+        amortization path.  Block geometry fits the operand shapes
+        (:meth:`fit`), padding where a tile does not divide: there is no
+        silent dense fallback for small or odd operands.
 
         ``op`` names this call site's tuning key (``geometry="auto"``): a
         distinct op — ``"moe_expert"``, a custom pipeline stage — resolves
@@ -363,21 +374,28 @@ class Runtime:
         kernel = self.kernel
         if not kernel.sparse and plan is None and plan_key is None:
             return kernel.matmul(a, b, bm=self.bm, bk=self.bk, bn=self.bn)
-        # one resolution path: tuned-policy overlay + shape clamp; with an
+        # one resolution path: tuned-policy overlay + shape fit; with an
         # explicit plan its geometry governs and only the lane dim is fitted
-        rt = self._resolved(op, a.shape, b.shape, a.dtype, plan=plan,
-                            density=density)
+        pol_rt = self._overlaid(op, a.shape, b.shape, a.dtype, plan=plan,
+                                density=density)
+        rt = pol_rt if plan is not None else pol_rt.fit(a.shape, b.shape)
+        m, n = a.shape[0], b.shape[1]
         if side == "B":
             if plan is None:
                 plan = rt.plan(b, key=plan_key, side="B")
             else:
                 plan = self._recovered_plan(plan, b.T)
+            # the tokens are this product's lanes: their tile comes from the
+            # lane target, not from the vocabulary's row block
+            bn = pol_rt.lane(m)
             out_t = kernel.matmul_planned(
-                plan, b.T, a.T, bn=rt.lane(a.shape[0], rt.bm), out_dtype=a.dtype,
+                plan, _pad_to(b.T, plan.shape),
+                _pad_to(a.T, (plan.shape[1], _round_up(m, bn))),
+                bn=bn, out_dtype=a.dtype,
                 plan_cache=self.plan_cache, plan_key=("B", plan_key),
                 compact_grid=rt.compact_grid, db=self._db,
             )
-            return out_t.T
+            return _crop(out_t, (n, m)).T
         if plan is None:
             if plan_key is None:
                 # keyless dynamic operand: plan inline (never cached), but
@@ -389,11 +407,15 @@ class Runtime:
                 plan = rt.plan(a, key=plan_key)
         else:
             plan = self._recovered_plan(plan, a)
-        return kernel.matmul_planned(
-            plan, a, b, bn=rt.lane(b.shape[1]), out_dtype=a.dtype,
+        bn = rt.lane(n)
+        out = kernel.matmul_planned(
+            plan, _pad_to(a, plan.shape),
+            _pad_to(b, (plan.shape[1], _round_up(n, bn))),
+            bn=bn, out_dtype=a.dtype,
             plan_cache=self.plan_cache, plan_key=("A", plan_key),
             compact_grid=rt.compact_grid, db=self._db,
         )
+        return _crop(out, (m, n))
 
     def matmul_fused(self, a, b, *, bias=None, residual=None,
                      activation: str = "none", plan: SparsityPlan | None = None,
@@ -411,12 +433,15 @@ class Runtime:
         (metadata only — for streams known dense, e.g. an FFN input) instead
         of planning its values.  Differentiable: both backward products take
         metadata-only plans (emitted mask / forward-plan transpose) for
-        ReLU-family activations.
+        ReLU-family activations.  The mask covers the tile grid of the
+        operands as padded to tile multiples (:meth:`fit`).
         """
         a, b = self._dtype_prologue(a, b)
         kernel = self.kernel
         rt = self._resolved(op, a.shape, b.shape, a.dtype, plan=plan,
                             density=density)
+        m, n = a.shape[0], b.shape[1]
+        bn = rt.lane(n)
         if not kernel.sparse and plan is None and plan_key is None:
             # dense shortcut (mirrors matmul's, including the plan_key
             # condition: a keyed call routes through the planned path so the
@@ -429,10 +454,10 @@ class Runtime:
                 jnp.dot(a, b, preferred_element_type=jnp.float32),
                 bias, residual, activation,
             )
-            bm_f, bn_f = rt.bm, rt.lane(b.shape[1])
-            m, n = out32.shape
+            mp, np_ = _round_up(m, rt.bm), _round_up(n, bn)
             mask = jnp.any(
-                out32.reshape(m // bm_f, bm_f, n // bn_f, bn_f) != 0, axis=(1, 3)
+                _pad_to(out32, (mp, np_)).reshape(mp // rt.bm, rt.bm, np_ // bn, bn)
+                != 0, axis=(1, 3)
             ).astype(jnp.int8)
             return out32.astype(a.dtype), mask
         kernel.check_platform()
@@ -443,27 +468,34 @@ class Runtime:
                 plan = rt.plan(a, key=plan_key)
         else:
             plan = self._recovered_plan(plan, a)
-        return kernel.matmul_fused(
-            plan, a, b, bias=bias, residual=residual, activation=activation,
-            bn=rt.lane(b.shape[1]), out_dtype=a.dtype,
+        mp, np_ = plan.shape[0], _round_up(n, bn)
+        out, mask = kernel.matmul_fused(
+            plan, _pad_to(a, plan.shape), _pad_to(b, (plan.shape[1], np_)),
+            bias=None if bias is None else _pad_to(bias, (np_,)),
+            residual=None if residual is None else _pad_to(residual, (mp, np_)),
+            activation=activation, bn=bn, out_dtype=a.dtype,
             plan_cache=self.plan_cache, plan_key=("A", plan_key),
             compact_grid=rt.compact_grid, db=self._db,
         )
+        return _crop(out, (m, n)), mask
 
-    def plan_for_fused_output(self, mask, h, w) -> SparsityPlan:
+    def plan_for_fused_output(self, mask, h, w, *, k: int) -> SparsityPlan:
         """Consumer plan for a fused matmul's output ``h`` (about to be the
         sparse stream of ``h @ w``), built from the emitted ``mask`` alone.
 
-        Re-derives the producer's block geometry from the shapes
-        (``bm = M / Mb``, ``mask_bn = N / Nb``) and coarsens to this
+        Recovers the producer's block geometry and coarsens to this
         runtime's fitted contraction block when divisible — the single
         place that geometry recovery lives, shared by every emitted-mask
-        consumer (``sparse_ffn``, the transformer FFN).
+        consumer (``sparse_ffn``, the transformer FFN).  ``k`` is the
+        producer's contraction dim: the producer's tiles are re-resolved
+        exactly as :meth:`matmul_fused` resolved them, which also recovers
+        a grid the producer padded.
         """
+        (m, n), (mb, nb) = h.shape, mask.shape
+        prod = self._resolved("matmul_fused", (m, k), (k, n), h.dtype)
+        bm, mask_bn = prod.bm, prod.lane(n)
         return plan_from_emitted_mask(
-            mask, h.shape, h.dtype,
-            bm=h.shape[0] // mask.shape[0],
-            mask_bn=h.shape[1] // mask.shape[1],
+            mask, (mb * bm, nb * mask_bn), h.dtype, bm=bm, mask_bn=mask_bn,
             bk=self.fit(h.shape, w.shape).bk,
         )
 
@@ -483,13 +515,18 @@ class Runtime:
             plan = self._resolved(
                 "matmul", a.shape, b.shape, a.dtype
             ).plan(a, key=plan_key)
+        bn = self.lane(g.shape[1])
         ctx = PlannedVJP(
-            backend=self.backend, bm=plan.bm, bk=plan.bk,
-            bn=self.lane(g.shape[1]),
+            backend=self.backend, bm=plan.bm, bk=plan.bk, bn=bn,
             cache=self.plan_cache, key=("A", plan_key),
             compact_grid=self.compact_grid, db=self._db,
         )
-        return planned_matmul_grads(ctx, plan.nnz, plan.idx, a, b, g)
+        np_ = _round_up(g.shape[1], bn)
+        da, db = planned_matmul_grads(
+            ctx, plan.nnz, plan.idx, _pad_to(a, plan.shape),
+            _pad_to(b, (plan.shape[1], np_)), _pad_to(g, (plan.shape[0], np_)),
+        )
+        return _crop(da, a.shape), _crop(db, b.shape)
 
     def matmul_sharded(self, a, b, *, axis: str = "M",
                        plan: SparsityPlan | None = None, plan_key=None,
@@ -521,13 +558,17 @@ class Runtime:
         if plan is None:
             rt.kernel.check_platform()
             plan = rt.plan(a, key=plan_key)
-        return spmm.sharded_matmul(
-            plan, a, b, bn=rt.lane(b.shape[1]),
+        (m, _), n = a.shape, b.shape[1]
+        bn = rt.lane(n)
+        out = spmm.sharded_matmul(
+            plan, _pad_to(a, plan.shape),
+            _pad_to(b, (plan.shape[1], _round_up(n, bn))), bn=bn,
             backend=self.backend, policy=policy, axis=axis, balance=balance,
             out_dtype=a.dtype, plan_cache=self.plan_cache,
             plan_key=("A", plan_key), compact_grid=rt.compact_grid,
             validate=self.validate, db=self._db,
         )
+        return _crop(out, (m, n))
 
     def matmul_fused_sharded(self, a, b, *, bias=None, residual=None,
                              activation: str = "none", axis: str = "M",
@@ -550,18 +591,24 @@ class Runtime:
         a, b = self._dtype_prologue(a, b)
         rt = self._resolved("matmul_fused", a.shape, b.shape, a.dtype, plan=plan)
         rt.kernel.check_platform()
+        m, n = a.shape[0], b.shape[1]
         if plan is None:
             if assume_dense:
                 plan = dense_operand_plan(a.shape, a.dtype, bm=rt.bm, bk=rt.bk)
             else:
                 plan = rt.plan(a, key=plan_key)
-        return spmm.sharded_matmul_fused(
-            plan, a, b, bias=bias, residual=residual, activation=activation,
-            bn=rt.lane(b.shape[1]), backend=self.backend,
+        bn = rt.lane(n)
+        mp, np_ = plan.shape[0], _round_up(n, bn)
+        out, mask = spmm.sharded_matmul_fused(
+            plan, _pad_to(a, plan.shape), _pad_to(b, (plan.shape[1], np_)),
+            bias=None if bias is None else _pad_to(bias, (np_,)),
+            residual=None if residual is None else _pad_to(residual, (mp, np_)),
+            activation=activation, bn=bn, backend=self.backend,
             policy=policy, axis=axis, balance=balance, out_dtype=a.dtype,
             plan_cache=self.plan_cache, plan_key=("A", plan_key),
             compact_grid=rt.compact_grid, validate=self.validate, db=self._db,
         )
+        return _crop(out, (m, n)), mask
 
     def sparse_ffn(self, x, w1, w2, *, activation: str = "relu"):
         """FFN whose second matmul exploits the activation sparsity the
@@ -605,8 +652,8 @@ class Runtime:
         h, mask = self.matmul_fused(
             x2, w1, activation=activation, assume_dense=True
         )
-        out = self.matmul(h, w2, plan=self.plan_for_fused_output(mask, h, w2),
-                          op="ffn")
+        plan = self.plan_for_fused_output(mask, h, w2, k=x2.shape[1])
+        out = self.matmul(h, w2, plan=plan, op="ffn")
         return out.reshape(*lead, w2.shape[-1])
 
     # -- serving cache layout ---------------------------------------------
@@ -641,6 +688,17 @@ class Runtime:
 
         return M.init_cache(cfg, slots, max_len)
 
+    def replicated(self, tree):
+        """``tree`` placed whole on every device of the mesh (unchanged
+        without one) — so per-slot engine state carries the same sharding
+        before the first decode call as after it, and the decode program
+        traces once."""
+        if self.mesh is None:
+            return tree
+        from jax.sharding import NamedSharding, PartitionSpec  # local: light
+
+        return jax.device_put(tree, NamedSharding(self.mesh, PartitionSpec()))
+
     def write_slot(self, cfg, caches, slot: int, part):
         """Write one request's caches (batch=1, already grown to the packed
         ``max_len`` via :meth:`grow_caches`) into batch slot ``slot``.
@@ -662,6 +720,14 @@ class Runtime:
             return jax.lax.dynamic_update_slice(full, p.astype(full.dtype), tuple(start))
 
         return jax.tree.map(place, caches, part, axes)
+
+
+def _crop(x, shape):
+    """The leading ``shape`` corner of a 2-D result computed on padded
+    operands (``x`` itself when nothing was padded)."""
+    if tuple(x.shape) == tuple(shape):
+        return x
+    return x[: shape[0], : shape[1]]
 
 
 @functools.lru_cache(maxsize=None)

@@ -362,16 +362,19 @@ class ServeEngine:
         self.caches, slots = self._alloc_slot_caches(cfg, slots)
         self.sched.num_slots = slots
         self.sched.table = self.sched.table[:slots]
-        self.tok = jnp.zeros((slots,), jnp.int32)
-        self.pos = jnp.zeros((slots,), jnp.int32)
-        self.active = jnp.zeros((slots,), bool)
-        self.remaining = jnp.zeros((slots,), jnp.int32)
-        self.keys = jnp.zeros((slots, 2), jnp.uint32)
+        (self.tok, self.pos, self.active, self.remaining, self.keys,
+         self._zero_poison) = self.rt.replicated((
+            jnp.zeros((slots,), jnp.int32),
+            jnp.zeros((slots,), jnp.int32),
+            jnp.zeros((slots,), bool),
+            jnp.zeros((slots,), jnp.int32),
+            jnp.zeros((slots, 2), jnp.uint32),
+            jnp.zeros((slots,), jnp.int32),
+        ))
         # counters
         self.tokens_out = 0
         self.chunks_run = 0
         self.steps_run = 0
-        self._zero_poison = jnp.zeros((slots,), jnp.int32)
 
     def _alloc_slot_caches(self, cfg, slots: int):
         """Allocate the packed decode caches, halving ``slots`` (down to 1)
@@ -629,14 +632,8 @@ class ServeEngine:
         finished += self._retire_finished()
         if not bool(np.any(np.asarray(self.active))):
             return finished
-        poison = self._chunk_poison()
-        out = _decode_chunk(
-            self.params, self.caches, self.tok, self.pos, self.active,
-            self.remaining, self.keys, poison,
-            cfg=self.cfg, rt=self.rt, steps=self.chunk,
-            temperature=self.temperature, eos_id=self.eos_id, pad_id=self.pad_id,
-            watchdog=self.watchdog,
-        )
+        out = _decode_chunk(*self._decode_args(self._chunk_poison()),
+                            **self._decode_statics())
         (self.caches, self.tok, self.pos, self.active, self.remaining,
          self.keys, toks, emitted, faulted) = out
         self.chunks_run += 1
@@ -659,6 +656,24 @@ class ServeEngine:
                                 emitted=len(req.tokens))
         finished += self._retire_finished()
         return finished
+
+    def _decode_args(self, poison):
+        return (self.params, self.caches, self.tok, self.pos, self.active,
+                self.remaining, self.keys, poison)
+
+    def _decode_statics(self) -> dict:
+        return dict(
+            cfg=self.cfg, rt=self.rt, steps=self.chunk,
+            temperature=self.temperature, eos_id=self.eos_id,
+            pad_id=self.pad_id, watchdog=self.watchdog,
+        )
+
+    def lower_decode(self):
+        """The decode-chunk program :meth:`step` runs, lowered at the
+        engine's current state (``.compile().as_text()`` shows which kernels
+        it holds) without running it."""
+        return _decode_chunk.lower(*self._decode_args(self._zero_poison),
+                                   **self._decode_statics())
 
     def _chunk_poison(self):
         """The [slots] poison-code vector for this chunk (all zeros — one

@@ -111,7 +111,6 @@ def make_train_step(
     opt_cfg: OptConfig,
     *,
     microbatches: int = 1,
-    donate: bool = True,
     sparsity_taps: bool = False,
     dynamic_sparsity=None,
     guard_nonfinite: bool = False,

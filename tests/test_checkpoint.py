@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.manager import all_steps, latest_step, restore, save
+from repro.launch.mesh import make_mesh
 
 
 def _tree(key, scale=1.0):
@@ -36,7 +37,7 @@ def test_elastic_restore_with_shardings(tmp_path):
     """Restore onto explicit (single-device) shardings: the elastic path."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     t = _tree(jax.random.PRNGKey(1))
     save(str(tmp_path), 7, t)
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), t)

@@ -4,7 +4,8 @@ injected faults, end to end through their real CLIs.
 Covers the degradation paths the unit suite cannot reach in place:
 
 * ``launch.train`` straggler mitigation (``--step-deadline``) — triggered
-  deterministically by a ``step_stall`` injection — checkpoints + aborts;
+  deterministically by a ``step_stall`` injection — checkpoints + aborts
+  with exit code 4;
 * ``launch.train`` preemption (``preempt`` raises SIGTERM through the real
   ``PreemptionGuard``) — checkpoints + exits cleanly;
 * ``launch.train`` non-finite guard: an isolated NaN step is skipped and
@@ -28,11 +29,14 @@ _SERVE_ARGS = ["--smoke", "--requests", "4", "--slots", "2", "--new", "4",
 
 def test_train_straggler_deadline_checkpoints_and_aborts(tmp_path, capsys):
     """A stalled step past --step-deadline aborts the run with a checkpoint
-    (the fleet reschedules elsewhere) instead of hanging the job."""
-    launch_train.main(_TRAIN_ARGS + [
-        "--ckpt-dir", str(tmp_path), "--ckpt-every", "100",
-        "--step-deadline", "8", "--inject-faults", "step_stall@1:secs=10",
-    ])
+    (the fleet reschedules elsewhere) instead of hanging the job, and the
+    process exits non-zero (code 4) so the abort is never read as success."""
+    with pytest.raises(SystemExit) as exc:
+        launch_train.main(_TRAIN_ARGS + [
+            "--ckpt-dir", str(tmp_path), "--ckpt-every", "100",
+            "--step-deadline", "8", "--inject-faults", "step_stall@1:secs=10",
+        ])
+    assert exc.value.code == 4
     out = capsys.readouterr().out
     assert "exceeded deadline" in out
     assert all_steps(tmp_path) == [2]  # aborted at step 1: saved i+1

@@ -31,6 +31,7 @@ import pytest
 from repro import runtime as rtm
 from repro.analysis.plan_check import PlanVerificationError, check_plan
 from repro.configs import get_config, reduce_config
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.models.common import init_params
 from repro.resilience import (
@@ -497,7 +498,7 @@ def test_shard_failure_falls_back_to_unsharded(fused):
     plan = plan_operand(a, 8, 8)
     req = KernelRequest(nnz=plan.nnz, idx=plan.idx, a=a, b=b,
                         bm=8, bk=8, bn=8, workqueue=plan.workqueue())
-    policy = ShardingPolicy(mesh=jax.make_mesh((4, 2), ("data", "model")))
+    policy = ShardingPolicy(mesh=make_mesh((4, 2), ("data", "model")))
     be = get_backend("reference")
     log = ResilienceLog()
     fp = FaultPlan.parse("shard_fail@0:count=99")
@@ -535,7 +536,7 @@ def test_no_fault_plan_no_shard_overhead_path():
     plan = plan_operand(a, 8, 8)
     req = KernelRequest(nnz=plan.nnz, idx=plan.idx, a=a, b=b,
                         bm=8, bk=8, bn=8, workqueue=plan.workqueue())
-    policy = ShardingPolicy(mesh=jax.make_mesh((4, 2), ("data", "model")))
+    policy = ShardingPolicy(mesh=make_mesh((4, 2), ("data", "model")))
     want = get_backend("reference").execute_planned(req)
     got = spmm.sharded_execute_planned("reference", req, policy, axis="M")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -563,7 +564,7 @@ def test_guarded_step_skips_poisoned_update(train_setup, code, what):
     from repro.train.step import make_train_step
 
     cfg, ocfg, params, opt, batch = train_setup
-    step = jax.jit(make_train_step(cfg, ocfg, donate=False,
+    step = jax.jit(make_train_step(cfg, ocfg,
                                    guard_nonfinite=True))
     p2, o2, m = step(params, opt, batch, poison=jnp.int32(code))
     assert int(m["nonfinite"]) == 1, f"NaN {what} undetected"
@@ -579,8 +580,8 @@ def test_guard_is_free_on_clean_steps(train_setup):
     from repro.train.step import make_train_step
 
     cfg, ocfg, params, opt, batch = train_setup
-    bare = jax.jit(make_train_step(cfg, ocfg, donate=False))
-    guarded = jax.jit(make_train_step(cfg, ocfg, donate=False,
+    bare = jax.jit(make_train_step(cfg, ocfg))
+    guarded = jax.jit(make_train_step(cfg, ocfg,
                                       guard_nonfinite=True))
     p1, o1, m1 = bare(params, opt, batch)
     p2, o2, m2 = guarded(params, opt, batch, poison=jnp.int32(0))

@@ -91,11 +91,16 @@ def test_capability_checks():
     # the raw backend API still rejects indivisible geometry ...
     with pytest.raises(BackendCapabilityError, match="not divisible"):
         interp.check_geometry(33, 64, 32, bm=16, bk=32, bn=16)
-    # ... but the Runtime auto-clamps, so it supports any shape on-platform
+    # ... but the Runtime fits tiles and pads, so it supports any shape
+    # on-platform: 33 rows keep the 16-row tile and run padded to 48
     rt = Runtime(backend="interpret", bm=16, bk=32, bn=16)
     assert rt.supports_matmul((33, 64), (64, 32))
     fitted = rt.fit((33, 64), (64, 32))
-    assert (fitted.bm, fitted.bk, fitted.bn) == (11, 32, 16)
+    assert (fitted.bm, fitted.bk, fitted.bn) == (16, 32, 16)
+    # chip-legal at the default targets: a multiple of 128 or the whole dim
+    wide = Runtime(backend="interpret").fit((200, 2560), (2560, 50280))
+    assert (wide.bm, wide.bk, wide.bn) == (128, 512, 128)
+    assert Runtime(backend="interpret").fit((4, 2560), (2560, 4)).bm == 4
 
 
 def test_register_custom_backend():

@@ -15,6 +15,7 @@ import pytest
 
 from repro import runtime as rtm
 from repro.kernels.ref import plan_workqueue_ref
+from repro.launch.mesh import make_mesh
 from repro.parallel import spmm
 from repro.parallel.sharding import ShardingPolicy
 from repro.runtime import (
@@ -35,7 +36,7 @@ BM = BK = BN = 8
 
 
 def _mixed_mesh():
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return make_mesh((4, 2), ("data", "model"))
 
 
 def _powerlaw_operand(rng, m=512, k=128, *, mean_density=0.5):

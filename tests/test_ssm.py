@@ -52,3 +52,22 @@ def test_prefill_decode_continuity():
     np.testing.assert_allclose(
         np.asarray(last[:, 0]), np.asarray(full[:, -1]), rtol=2e-2, atol=2e-2
     )
+
+
+def test_ssd_chunked_grads_finite_when_decay_overflows_exp():
+    """A full 128-step chunk at dt = 1 puts cum_i - cum_j near 348 above the
+    diagonal, past float32 exp's range (~88): the masked-out half of the
+    intra-chunk decay must not turn into 0 * inf = NaN in the backward."""
+    bsz, s, h, p, n = 1, 256, 2, 4, 8
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(k[0], (bsz, s, h, p), jnp.float32)
+    b = jax.random.normal(k[1], (bsz, s, n), jnp.float32)
+    c = jax.random.normal(k[2], (bsz, s, n), jnp.float32)
+    dt = jnp.ones((bsz, s, h), jnp.float32)
+
+    def loss(x, dt):
+        y, _ = ssd_chunked(x, dt, jnp.ones((h,)), b, c, chunk=128)
+        return jnp.sum(y)
+
+    gx, gdt = jax.grad(loss, argnums=(0, 1))(x, dt)
+    assert bool(jnp.all(jnp.isfinite(gx))) and bool(jnp.all(jnp.isfinite(gdt)))
