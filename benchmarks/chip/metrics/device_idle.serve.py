@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device
+(profiler trace: one minus the union of the device operations' intervals
+over the window)."""
+
+
+def read(run):
+    if run.kind != "serve" or run.trace is None or run.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
