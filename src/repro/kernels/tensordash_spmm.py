@@ -670,6 +670,7 @@ def tensordash_matmul_planned(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
+        name="tensordash_matmul_planned",
     )(*operands)
 
 
@@ -778,6 +779,7 @@ def tensordash_matmul_fused(
         ],
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
+        name="tensordash_matmul_fused",
     )(*operands)
     return out, mask[:, 0, ::_MASK_LANES].astype(jnp.int8)
 

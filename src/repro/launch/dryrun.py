@@ -9,8 +9,7 @@ the production meshes, with no device allocation (ShapeDtypeStruct inputs).
         --shape train_4k --mesh pod
 
 Results (memory analysis, cost analysis, roofline terms, collective
-breakdown) are cached incrementally in ``results/dryrun.json`` and rendered
-into EXPERIMENTS.md by ``repro.launch.report``.
+breakdown) are cached incrementally in ``results/dryrun.json``.
 
 NOTE: the XLA_FLAGS line above MUST run before any other import — jax locks
 the device count at first init.  Everything below the flag is ordinary code.

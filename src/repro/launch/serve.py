@@ -178,6 +178,7 @@ def main(argv=None) -> None:
     reqs = list(eng._requests.values())
     ok = [r for r in reqs if r.ok]
     ttft = [r.t_first - r.arrival for r in reqs if r.t_first > 0.0]
+    queue = [r.t_admit - r.arrival for r in reqs if r.t_admit > 0.0]
     e2e = [r.t_finish - r.arrival for r in ok]
     st = eng.stats()
     pc = st["plan_cache"]
@@ -186,6 +187,9 @@ def main(argv=None) -> None:
     print(f"served {st['tokens_out']} tokens in {dt:.2f}s "
           f"({st['tokens_out']/dt:.1f} tok/s); decode program traced "
           f"{st['decode_traces']}x, {st['chunks_run']} chunks")
+    print(f"admitted {st['admitted']} requests in {st['prefill_groups']} "
+          f"prefill groups ({st['prefill_tokens']} prompt tokens); "
+          f"queue wait p50={_ms(_pct(queue,50))} p95={_ms(_pct(queue,95))}")
     print(f"latency  ttft p50={_ms(_pct(ttft,50))} p95={_ms(_pct(ttft,95))}"
           f"   e2e p50={_ms(_pct(e2e,50))} p95={_ms(_pct(e2e,95))}")
     print("finish reasons: " + ", ".join(

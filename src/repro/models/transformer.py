@@ -157,6 +157,7 @@ def mlp_fwd(params, cfg: ModelConfig, x, taps: dict | None = None, mesh=None, rt
     return h @ params["w_down"]
 
 
+@jax.named_scope("head")
 def head_matmul(cfg: ModelConfig, h, lm_head):
     """``h @ lm_head`` through the active runtime.
 
@@ -227,31 +228,33 @@ def _block_fwd(params, cfg: ModelConfig, h, positions, is_global, mesh, probe=No
     returns the FFN activation's measured :class:`SparsityStats` (the Eq. 1
     A stream)."""
     zero_centered = cfg.post_norms  # gemma-style norms
-    a = rms_norm(h, params["ln1"], zero_centered=zero_centered)
-    if cfg.use_mla:
-        a = mla_mod.mla_fwd(params["attn"], mla_config(cfg), a, positions, mesh=mesh)
-    else:
-        a = attn.attention_fwd(params["attn"], attn_config(cfg), a, positions, is_global=is_global, mesh=mesh)
-    # pin the projection outputs themselves: lets GSPMD reduce-scatter the
-    # partial sums (sequence parallelism) instead of all-reducing the full
-    # activation before the residual add (§Perf iteration 6)
-    a = constrain(a, mesh, (DP, _seq_ax(cfg), None))
-    if cfg.post_norms:
-        a = rms_norm(a, params["post_attn_norm"], zero_centered=True)
+    with jax.named_scope("attention"):
+        a = rms_norm(h, params["ln1"], zero_centered=zero_centered)
+        if cfg.use_mla:
+            a = mla_mod.mla_fwd(params["attn"], mla_config(cfg), a, positions, mesh=mesh)
+        else:
+            a = attn.attention_fwd(params["attn"], attn_config(cfg), a, positions, is_global=is_global, mesh=mesh)
+        # pin the projection outputs themselves: lets GSPMD reduce-scatter
+        # the partial sums (sequence parallelism) instead of all-reducing
+        # the full activation before the residual add (§Perf iteration 6)
+        a = constrain(a, mesh, (DP, _seq_ax(cfg), None))
+        if cfg.post_norms:
+            a = rms_norm(a, params["post_attn_norm"], zero_centered=True)
     h = h + a
-    m = rms_norm(h, params["ln2"], zero_centered=zero_centered)
-    stats = None
-    if cfg.num_experts and "router" in params["mlp"]:
-        m = moe_mod.moe_ffn(params["mlp"], moe_config(cfg), m, mesh=mesh)
-        if taps:  # no hidden tap inside expert dispatch: measure the output
-            stats = {"ffn_act": sps.measure(m)}
-    else:
-        t = {} if taps else None
-        m = mlp_fwd(params["mlp"], cfg, m, taps=t, mesh=mesh)
-        stats = t
-    m = constrain(m, mesh, (DP, _seq_ax(cfg), None))
-    if cfg.post_norms:
-        m = rms_norm(m, params["post_mlp_norm"], zero_centered=True)
+    with jax.named_scope("mlp"):
+        m = rms_norm(h, params["ln2"], zero_centered=zero_centered)
+        stats = None
+        if cfg.num_experts and "router" in params["mlp"]:
+            m = moe_mod.moe_ffn(params["mlp"], moe_config(cfg), m, mesh=mesh)
+            if taps:  # no hidden tap inside expert dispatch: measure the output
+                stats = {"ffn_act": sps.measure(m)}
+        else:
+            t = {} if taps else None
+            m = mlp_fwd(params["mlp"], cfg, m, taps=t, mesh=mesh)
+            stats = t
+        m = constrain(m, mesh, (DP, _seq_ax(cfg), None))
+        if cfg.post_norms:
+            m = rms_norm(m, params["post_mlp_norm"], zero_centered=True)
     if probe is not None:
         # zero probe: d loss / d probe == G_O at the MLP output; cast so the
         # add never promotes the activation dtype (bf16 models stay bf16)
@@ -261,23 +264,25 @@ def _block_fwd(params, cfg: ModelConfig, h, positions, is_global, mesh, probe=No
 
 def _block_decode(params, cfg: ModelConfig, h, cache, pos, is_global, mesh):
     zero_centered = cfg.post_norms
-    a = rms_norm(h, params["ln1"], zero_centered=zero_centered)
-    if cfg.use_mla:
-        a, cache = mla_mod.mla_decode(params["attn"], mla_config(cfg), a, cache, pos, mesh=mesh)
-    else:
-        a, cache = attn.attention_decode(
-            params["attn"], attn_config(cfg), a, cache, pos, is_global=is_global, mesh=mesh
-        )
-    if cfg.post_norms:
-        a = rms_norm(a, params["post_attn_norm"], zero_centered=True)
+    with jax.named_scope("attention"):
+        a = rms_norm(h, params["ln1"], zero_centered=zero_centered)
+        if cfg.use_mla:
+            a, cache = mla_mod.mla_decode(params["attn"], mla_config(cfg), a, cache, pos, mesh=mesh)
+        else:
+            a, cache = attn.attention_decode(
+                params["attn"], attn_config(cfg), a, cache, pos, is_global=is_global, mesh=mesh
+            )
+        if cfg.post_norms:
+            a = rms_norm(a, params["post_attn_norm"], zero_centered=True)
     h = h + a
-    m = rms_norm(h, params["ln2"], zero_centered=zero_centered)
-    if cfg.num_experts and "router" in params["mlp"]:
-        m = moe_mod.moe_ffn(params["mlp"], moe_config(cfg), m, mesh=mesh, seq_sharded=False)
-    else:
-        m = mlp_fwd(params["mlp"], cfg, m, mesh=mesh)
-    if cfg.post_norms:
-        m = rms_norm(m, params["post_mlp_norm"], zero_centered=True)
+    with jax.named_scope("mlp"):
+        m = rms_norm(h, params["ln2"], zero_centered=zero_centered)
+        if cfg.num_experts and "router" in params["mlp"]:
+            m = moe_mod.moe_ffn(params["mlp"], moe_config(cfg), m, mesh=mesh, seq_sharded=False)
+        else:
+            m = mlp_fwd(params["mlp"], cfg, m, mesh=mesh)
+        if cfg.post_norms:
+            m = rms_norm(m, params["post_mlp_norm"], zero_centered=True)
     return constrain(h + m, mesh, (DP, _seq_ax(cfg), None)), cache
 
 
@@ -472,27 +477,29 @@ def prefill(params, cfg: ModelConfig, batch, mesh=None):
     def body(carry, inp):
         p, g = inp
         zc = cfg.post_norms
-        a = rms_norm(carry, p["ln1"], zero_centered=zc)
-        if cfg.use_mla:
-            c_kv, k_pe = mla_mod._latent_kv(p["attn"], mla_config(cfg), a, positions if positions.ndim == 1 else jnp.arange(s))
-            a = mla_mod.mla_fwd(p["attn"], mla_config(cfg), a, positions if positions.ndim == 1 else jnp.arange(s), mesh=mesh)
-            cache = mla_mod.MLACache(c_kv=c_kv, k_pe=k_pe)
-        else:
-            a, cache = attn.attention_fwd(
-                p["attn"], attn_config(cfg), a, positions, is_global=g, return_cache=True, mesh=mesh
-            )
-        a = constrain(a, mesh, (DP, _seq_ax(cfg), None))
-        if cfg.post_norms:
-            a = rms_norm(a, p["post_attn_norm"], zero_centered=True)
+        with jax.named_scope("attention"):
+            a = rms_norm(carry, p["ln1"], zero_centered=zc)
+            if cfg.use_mla:
+                c_kv, k_pe = mla_mod._latent_kv(p["attn"], mla_config(cfg), a, positions if positions.ndim == 1 else jnp.arange(s))
+                a = mla_mod.mla_fwd(p["attn"], mla_config(cfg), a, positions if positions.ndim == 1 else jnp.arange(s), mesh=mesh)
+                cache = mla_mod.MLACache(c_kv=c_kv, k_pe=k_pe)
+            else:
+                a, cache = attn.attention_fwd(
+                    p["attn"], attn_config(cfg), a, positions, is_global=g, return_cache=True, mesh=mesh
+                )
+            a = constrain(a, mesh, (DP, _seq_ax(cfg), None))
+            if cfg.post_norms:
+                a = rms_norm(a, p["post_attn_norm"], zero_centered=True)
         hh = carry + a
-        m = rms_norm(hh, p["ln2"], zero_centered=zc)
-        if cfg.num_experts and "router" in p["mlp"]:
-            m = moe_mod.moe_ffn(p["mlp"], moe_config(cfg), m, mesh=mesh)
-        else:
-            m = mlp_fwd(p["mlp"], cfg, m, mesh=mesh)
-        m = constrain(m, mesh, (DP, _seq_ax(cfg), None))
-        if cfg.post_norms:
-            m = rms_norm(m, p["post_mlp_norm"], zero_centered=True)
+        with jax.named_scope("mlp"):
+            m = rms_norm(hh, p["ln2"], zero_centered=zc)
+            if cfg.num_experts and "router" in p["mlp"]:
+                m = moe_mod.moe_ffn(p["mlp"], moe_config(cfg), m, mesh=mesh)
+            else:
+                m = mlp_fwd(p["mlp"], cfg, m, mesh=mesh)
+            m = constrain(m, mesh, (DP, _seq_ax(cfg), None))
+            if cfg.post_norms:
+                m = rms_norm(m, p["post_mlp_norm"], zero_centered=True)
         return constrain(hh + m, mesh, (DP, _seq_ax(cfg), None)), cache
 
     if cfg.remat:

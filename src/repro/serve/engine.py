@@ -61,6 +61,14 @@ perturbing healthy batch-mates (their sampling is per-row, their KV rows
 are per-slot — bit-identity is asserted by the chaos suite) and without
 changing the scan's shape signature.  Every degradation lands in the
 engine's :class:`repro.resilience.ResilienceLog`.
+
+Tracing: ``step`` records host spans (:mod:`repro.trace`, names
+``serve.*``) around the work it already does — admission of each group,
+its prefill, cache growth, each request's slot write and slot state, the
+first-token fetch, the decode chunk through its token fetch, and retiring.
+They land in a ``jax.profiler`` trace on the device's clock when one is
+active, and cost about a microsecond each when none is; no span adds a
+device sync.
 """
 from __future__ import annotations
 
@@ -76,6 +84,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import runtime as rtm
+from repro import trace as tr
 from repro.configs.base import ModelConfig
 from repro.models import model as M
 from repro.resilience import faults as rfaults
@@ -218,7 +227,7 @@ class Request:
     slot: int | None = None
     retries: int = 0  # admission retries after transient (alloc) failures
     t_submit: float = 0.0
-    t_admit: float = 0.0
+    t_admit: float = 0.0  # its group's admission started (before prefill)
     t_first: float = 0.0  # first token (produced at admission, from prefill)
     t_finish: float = 0.0
 
@@ -375,6 +384,9 @@ class ServeEngine:
         self.tokens_out = 0
         self.chunks_run = 0
         self.steps_run = 0
+        self.admitted = 0  # requests admitted into a slot
+        self.prefill_groups = 0  # prefill calls (one per admitted group)
+        self.prefill_tokens = 0  # prompt tokens prefilled
 
     def _alloc_slot_caches(self, cfg, slots: int):
         """Allocate the packed decode caches, halving ``slots`` (down to 1)
@@ -517,46 +529,61 @@ class ServeEngine:
         each request's caches into its slot (per-slot cache views)."""
         g = len(placements)
         s = placements[0][1].prompt.shape[0]
-        prompts = jnp.stack([r.prompt for _, r in placements])
-        with rtm.use(self.rt):
-            logits, caches = M.prefill(self.params, self.cfg, {"tokens": prompts})
-            rfaults.maybe_alloc_failure(
-                self.fault_plan or rfaults.active(), "grow_caches"
-            )
-            part = self.rt.grow_caches(self.cfg, caches, g, self.max_len)
-            axes = rtm.cache_batch_axes(self.cfg)
-            for j, (slot, _) in enumerate(placements):
-                row = jax.tree.map(
-                    lambda x, ax: jax.lax.slice_in_dim(x, j, j + 1, axis=ax),
-                    part, axes,
+        with tr.span(tr.SERVE_ADMIT, n=g, s=s, rid=placements[0][1].rid):
+            now = self._now()
+            for _, req in placements:
+                req.t_admit = now
+            prompts = jnp.stack([r.prompt for _, r in placements])
+            with rtm.use(self.rt):
+                with tr.span(tr.SERVE_PREFILL, n=g, s=s):
+                    logits, caches = M.prefill(self.params, self.cfg,
+                                               {"tokens": prompts})
+                rfaults.maybe_alloc_failure(
+                    self.fault_plan or rfaults.active(), "grow_caches"
                 )
-                self.caches = self.rt.write_slot(self.cfg, self.caches, slot, row)
-        # per-request RNG: fold the rid in, split BEFORE the first sample —
-        # the first token and every later token draw from distinct subkeys,
-        # and the stream depends only on (seed, rid), never on the batch
-        keys = jnp.stack(
-            [jax.random.fold_in(self._base_key, r.rid) for _, r in placements]
-        )
-        splits = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-        carried, subs = splits[:, 0], splits[:, 1]
-        firsts = np.asarray(_sample_rows(
-            logits[:, -1].astype(jnp.float32), subs, self.temperature
-        ))
-        now = self._now()
-        for j, (slot, req) in enumerate(placements):
-            first = int(firsts[j])
-            req.t_admit = req.t_first = now
-            req.tokens.append(first)
-            self.tokens_out += 1
-            is_eos = self.eos_id is not None and first == self.eos_id
-            done = req.max_new <= 1 or is_eos
-            self.tok = self.tok.at[slot].set(first)
-            self.pos = self.pos.at[slot].set(s)
-            self.remaining = self.remaining.at[slot].set(req.max_new - 1)
-            self.keys = self.keys.at[slot].set(carried[j])
-            self.active = self.active.at[slot].set(not done)
-            if done:
-                req.finish_reason = "eos" if is_eos else "length"
+                with tr.span(tr.SERVE_GROW, n=g):
+                    part = self.rt.grow_caches(self.cfg, caches, g, self.max_len)
+                axes = rtm.cache_batch_axes(self.cfg)
+                for j, (slot, req) in enumerate(placements):
+                    with tr.span(tr.SERVE_SLOT_WRITE, rid=req.rid, slot=slot):
+                        row = jax.tree.map(
+                            lambda x, ax: jax.lax.slice_in_dim(x, j, j + 1, axis=ax),
+                            part, axes,
+                        )
+                        self.caches = self.rt.write_slot(self.cfg, self.caches,
+                                                         slot, row)
+            with tr.span(tr.SERVE_FIRST_TOKEN, n=g):
+                # per-request RNG: fold the rid in, split BEFORE the first
+                # sample — the first token and every later token draw from
+                # distinct subkeys, and the stream depends only on (seed, rid),
+                # never on the batch
+                keys = jnp.stack(
+                    [jax.random.fold_in(self._base_key, r.rid) for _, r in placements]
+                )
+                splits = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+                carried, subs = splits[:, 0], splits[:, 1]
+                firsts = np.asarray(_sample_rows(
+                    logits[:, -1].astype(jnp.float32), subs, self.temperature
+                ))
+            now = self._now()
+            for j, (slot, req) in enumerate(placements):
+                first = int(firsts[j])
+                req.t_first = now
+                req.tokens.append(first)
+                self.tokens_out += 1
+                is_eos = self.eos_id is not None and first == self.eos_id
+                done = req.max_new <= 1 or is_eos
+                with tr.span(tr.SERVE_SLOT_STATE, rid=req.rid, slot=slot):
+                    self.tok = self.tok.at[slot].set(first)
+                    self.pos = self.pos.at[slot].set(s)
+                    self.remaining = self.remaining.at[slot].set(req.max_new - 1)
+                    self.keys = self.keys.at[slot].set(carried[j])
+                    self.active = self.active.at[slot].set(not done)
+                if done:
+                    req.finish_reason = "eos" if is_eos else "length"
+        self.admitted += g
+        self.prefill_groups += 1
+        self.prefill_tokens += g * s
 
     #: admission retries before a transient-alloc-failed request is failed
     MAX_ADMIT_RETRIES = 3
@@ -595,19 +622,20 @@ class ServeEngine:
 
     def _retire_finished(self) -> list[Request]:
         """Evict every occupied slot whose device state went inactive."""
-        active = np.asarray(self.active)
-        out = []
-        for slot, req in self.sched.occupied():
-            if not active[slot]:
-                req.finished = True
-                req.t_finish = self._now()
-                if req.finish_reason is None:
-                    last = req.tokens[-1] if req.tokens else None
-                    req.finish_reason = (
-                        "eos" if self.eos_id is not None and last == self.eos_id
-                        else "length"
-                    )
-                out.append(self.sched.evict(slot))
+        with tr.span(tr.SERVE_RETIRE):
+            active = np.asarray(self.active)
+            out = []
+            for slot, req in self.sched.occupied():
+                if not active[slot]:
+                    req.finished = True
+                    req.t_finish = self._now()
+                    if req.finish_reason is None:
+                        last = req.tokens[-1] if req.tokens else None
+                        req.finish_reason = (
+                            "eos" if self.eos_id is not None and last == self.eos_id
+                            else "length"
+                        )
+                    out.append(self.sched.evict(slot))
         return out
 
     # -- the serving loop --------------------------------------------------
@@ -619,43 +647,45 @@ class ServeEngine:
         watchdog retires poisoned slots in-graph, admission failures requeue
         or fail the one request, deadlines evict, shedding drops — healthy
         slots keep decoding bit-identically throughout."""
-        now = self._now()
-        if self.fault_plan is not None:
-            rfaults.stall(self.fault_plan, "step_stall",
-                          self.fault_plan.tick("serve.step"))
-        finished = self._expire(now)
-        finished += self._shed_to_budget(now)
-        self._admit_all()
-        finished += self._retire_finished()  # requests done at admission
-        # backfill slots freed by admission-time finishes before decoding
-        self._admit_all()
-        finished += self._retire_finished()
-        if not bool(np.any(np.asarray(self.active))):
+        with tr.span(tr.SERVE_STEP):
+            now = self._now()
+            if self.fault_plan is not None:
+                rfaults.stall(self.fault_plan, "step_stall",
+                              self.fault_plan.tick("serve.step"))
+            finished = self._expire(now)
+            finished += self._shed_to_budget(now)
+            self._admit_all()
+            finished += self._retire_finished()  # requests done at admission
+            # backfill slots freed by admission-time finishes before decoding
+            self._admit_all()
+            finished += self._retire_finished()
+            if not bool(np.any(np.asarray(self.active))):
+                return finished
+            with tr.span(tr.SERVE_DECODE, steps=self.chunk):
+                out = _decode_chunk(*self._decode_args(self._chunk_poison()),
+                                    **self._decode_statics())
+                (self.caches, self.tok, self.pos, self.active, self.remaining,
+                 self.keys, toks, emitted, faulted) = out
+                self.chunks_run += 1
+                self.steps_run += self.chunk
+                toks = np.asarray(toks)          # [steps, slots]
+                emitted = np.asarray(emitted)    # [steps, slots] bool
+                faulted = np.asarray(faulted)    # [slots] bool
+            for slot, req in self.sched.occupied():
+                new = toks[emitted[:, slot], slot].tolist()
+                req.tokens.extend(new)
+                self.tokens_out += len(new)
+                if faulted[slot]:
+                    # watchdog retired this slot in-graph; record the error
+                    # status before _retire_finished assigns a reason
+                    req.finish_reason = "error"
+                    req.error = "non-finite logits (watchdog)"
+                    self.log.record("nonfinite", "serve.decode.watchdog",
+                                    "retire-slot", rid=req.rid, slot=slot,
+                                    chunk=self.chunks_run - 1,
+                                    emitted=len(req.tokens))
+            finished += self._retire_finished()
             return finished
-        out = _decode_chunk(*self._decode_args(self._chunk_poison()),
-                            **self._decode_statics())
-        (self.caches, self.tok, self.pos, self.active, self.remaining,
-         self.keys, toks, emitted, faulted) = out
-        self.chunks_run += 1
-        self.steps_run += self.chunk
-        toks = np.asarray(toks)          # [steps, slots]
-        emitted = np.asarray(emitted)    # [steps, slots] bool
-        faulted = np.asarray(faulted)    # [slots] bool
-        for slot, req in self.sched.occupied():
-            new = toks[emitted[:, slot], slot].tolist()
-            req.tokens.extend(new)
-            self.tokens_out += len(new)
-            if faulted[slot]:
-                # watchdog retired this slot in-graph; record the error
-                # status before _retire_finished assigns a reason
-                req.finish_reason = "error"
-                req.error = "non-finite logits (watchdog)"
-                self.log.record("nonfinite", "serve.decode.watchdog",
-                                "retire-slot", rid=req.rid, slot=slot,
-                                chunk=self.chunks_run - 1,
-                                emitted=len(req.tokens))
-        finished += self._retire_finished()
-        return finished
 
     def _decode_args(self, poison):
         return (self.params, self.caches, self.tok, self.pos, self.active,
@@ -705,6 +735,9 @@ class ServeEngine:
             "tokens_out": self.tokens_out,
             "chunks_run": self.chunks_run,
             "steps_run": self.steps_run,
+            "admitted": self.admitted,
+            "prefill_groups": self.prefill_groups,
+            "prefill_tokens": self.prefill_tokens,
             "slots": self.sched.num_slots,
             "decode_traces": DECODE_TRACES,
             "plan_cache": self.rt.plan_cache.stats(),
