@@ -7,8 +7,10 @@ lengths and decode budgets through a fixed-capacity slot array.
 Execution policy (kernel backend, block geometry, plan cache) is one
 ``repro.runtime.Runtime``; the decode loop is a single jitted ``lax.scan``
 program, traced once and replayed as the scheduler admits, finishes and
-backfills requests.  Under a sparse backend the LM-head SparsityPlan is
-computed at the first prefill and replayed (cache hits) for every later one.
+backfills requests, and admission prefills through one jitted program per
+(group size, prompt length).  Under a sparse backend the LM-head
+SparsityPlan is computed at the first admission and replayed (cache hits)
+for every later one.
 """
 import argparse
 import time

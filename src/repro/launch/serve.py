@@ -186,7 +186,8 @@ def main(argv=None) -> None:
           f"chunk={args.chunk} requests={args.requests}")
     print(f"served {st['tokens_out']} tokens in {dt:.2f}s "
           f"({st['tokens_out']/dt:.1f} tok/s); decode program traced "
-          f"{st['decode_traces']}x, {st['chunks_run']} chunks")
+          f"{st['decode_traces']}x, prefill program traced "
+          f"{st['prefill_traces']}x, {st['chunks_run']} chunks")
     print(f"admitted {st['admitted']} requests in {st['prefill_groups']} "
           f"prefill groups ({st['prefill_tokens']} prompt tokens); "
           f"queue wait p50={_ms(_pct(queue,50))} p95={_ms(_pct(queue,95))}")

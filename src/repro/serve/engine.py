@@ -15,6 +15,11 @@ requests.  The engine is built so that amortization actually meets traffic:
   (``Runtime.write_slot``), so admission is a slot write, not a
   reallocation.
 
+* admission prefills each same-length group through one jitted program
+  (:func:`_prefill_group`), cached per ``(group size, prompt length)``:
+  after the first admission of a signature, a prefill is one dispatch
+  (``ServeEngine.stats()["prefill_traces"]``).
+
 * the decode loop is a single **jitted, ``lax.scan``-based program**
   (:func:`_decode_chunk`): ``chunk`` decode steps over all slots per call,
   cache buffers donated so XLA updates them in place.  Its shape signature
@@ -28,12 +33,14 @@ Per-slot sequence positions ride as an int32 ``[slots]`` vector through
 position, which is what lets one scan serve requests of different lengths
 simultaneously.
 
-Under a sparse runtime the LM-head plan is computed once at the first
-prefill (a ``plan_cache`` miss), replayed from ``rt.plan_cache`` on every
-later prefill (identity-validated hits), and inside the jitted decode scan
-it is part of the traced program — XLA hoists the scan-invariant weight
-plan out of the loop, so it is computed once per chunk call, not per token
-(observable via ``rt.plan_cache.stats()["traced"]``).  Execution goes
+Under a sparse runtime the LM-head weight is planned once, eagerly, at the
+first admission (a ``plan_cache`` miss) and replayed from
+``rt.plan_cache`` at every later one (identity-validated hits): that cached
+plan's ``total_work`` prices the work budget and feeds the per-plan skew
+report.  The jitted prefill and decode programs carry the plan as part of
+the traced program (observable via ``rt.plan_cache.stats()["traced"]``);
+in the decode scan XLA hoists the scan-invariant weight plan out of the
+loop, so it is computed once per chunk call, not per token.  Execution goes
 through the v3 ragged work-queue kernel (the runtime default): each decode
 step's LM-head matmul issues exactly ``sum(nnz)`` contraction grid steps —
 one per effectual block — instead of the full ``Kb`` per row, so a
@@ -129,6 +136,27 @@ def _sample_rows(logits, keys, temperature: float):
 #: the compile-count probe: continuous batching must keep this at one per
 #: (slots, chunk, cache-shape) signature for the life of the process.
 DECODE_TRACES = 0
+
+#: number of times the admission prefill program has been traced — one per
+#: (group size, prompt length) signature, never one per admission.
+PREFILL_TRACES = 0
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "rt"))
+def _prefill_group(params, prompts, *, cfg, rt):
+    """Prefill a same-length group of prompts [g, s] as one program.
+
+    Returns the last position's logits as float32 and the prefill caches.
+    The weights are an argument (never closed over, so never baked in as
+    constants) and nothing is donated: a failed admission keeps every
+    buffer it was handed.  Under a sparse runtime the head's weight-side
+    plan is part of the traced program, as in :func:`_decode_chunk`.
+    """
+    global PREFILL_TRACES
+    PREFILL_TRACES += 1
+    with rtm.use(rt):
+        logits, caches = M.prefill(params, cfg, {"tokens": prompts})
+    return logits[:, -1].astype(jnp.float32), caches
 
 
 @functools.partial(
@@ -450,9 +478,22 @@ class ServeEngine:
         """Per-token admission cost from the cached plans' ``total_work``
         (the exact v3 ragged-grid steps a decode step replays) — the
         ROADMAP's plan-aware cost model.  Falls back to 1.0 (token units)
-        when no plan is cached (dense runtime / cold cache)."""
+        when no plan is cached (dense runtime / before the first
+        admission's :meth:`_plan_head`)."""
         total = sum(ps["total_work"] for ps in self.rt.plan_cache.plan_stats())
         return float(total) if total > 0 else 1.0
+
+    def _plan_head(self, g: int) -> None:
+        """Plan the LM-head weight eagerly into ``rt.plan_cache``, at the
+        geometry a ``g``-row head matmul resolves: a miss at the first
+        admission, an identity-validated hit at every later one.  The
+        jitted programs plan in-trace and never cache, so this is the plan
+        :meth:`_plan_cost` and ``plan_stats()`` read."""
+        w = self.params.get("lm_head")
+        if not self.rt.wants_sparse or w is None or w.ndim != 2:
+            return
+        rt = self.rt._resolved("matmul", (g, w.shape[0]), w.shape, w.dtype)
+        rt.plan(w, key=("lm_head", id(w)), side="B")
 
     def _outstanding_work(self) -> float:
         cost = self._plan_cost()
@@ -536,8 +577,9 @@ class ServeEngine:
             prompts = jnp.stack([r.prompt for _, r in placements])
             with rtm.use(self.rt):
                 with tr.span(tr.SERVE_PREFILL, n=g, s=s):
-                    logits, caches = M.prefill(self.params, self.cfg,
-                                               {"tokens": prompts})
+                    self._plan_head(g)
+                    last, caches = _prefill_group(self.params, prompts,
+                                                  cfg=self.cfg, rt=self.rt)
                 rfaults.maybe_alloc_failure(
                     self.fault_plan or rfaults.active(), "grow_caches"
                 )
@@ -562,9 +604,7 @@ class ServeEngine:
                 )
                 splits = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
                 carried, subs = splits[:, 0], splits[:, 1]
-                firsts = np.asarray(_sample_rows(
-                    logits[:, -1].astype(jnp.float32), subs, self.temperature
-                ))
+                firsts = np.asarray(_sample_rows(last, subs, self.temperature))
             now = self._now()
             for j, (slot, req) in enumerate(placements):
                 first = int(firsts[j])
@@ -590,7 +630,8 @@ class ServeEngine:
 
     def _admit_all(self) -> None:
         """Admit pending requests into free slots, batching same-length
-        prompts into one prefill each (prefill compiles once per length).
+        prompts into one prefill each (one compiled prefill program per
+        group size and prompt length).
 
         A transient allocation failure during a group's prefill/slot-write
         is contained: the group's requests go back to the pending queue
@@ -725,12 +766,15 @@ class ServeEngine:
     def stats(self) -> dict:
         """Engine + plan-cache counters.
 
-        ``decode_traces`` (process-wide :data:`DECODE_TRACES`) is the
-        canonical compile-count probe.  The plan cache's ``traced`` counter
-        only moves when *this* runtime's cache was threaded through a trace:
-        two engines with equal-policy runtimes share one compiled decode
-        program (jit statics hash the policy, not the cache handle), so the
-        second engine's ``traced`` legitimately stays 0."""
+        ``decode_traces`` and ``prefill_traces`` (process-wide
+        :data:`DECODE_TRACES`, :data:`PREFILL_TRACES`) are the
+        compile-count probes: at least ``prefill_groups - prefill_traces``
+        of this engine's prefills replayed a compiled program.  The plan
+        cache's ``traced`` counter only moves when *this* runtime's cache
+        was threaded through a trace: two engines with equal-policy
+        runtimes share one compiled decode and prefill program (jit statics
+        hash the policy, not the cache handle), so the second engine's
+        ``traced`` legitimately stays 0."""
         return {
             "tokens_out": self.tokens_out,
             "chunks_run": self.chunks_run,
@@ -740,6 +784,7 @@ class ServeEngine:
             "prefill_tokens": self.prefill_tokens,
             "slots": self.sched.num_slots,
             "decode_traces": DECODE_TRACES,
+            "prefill_traces": PREFILL_TRACES,
             "plan_cache": self.rt.plan_cache.stats(),
             "resilience_events": len(self.log),
         }

@@ -21,7 +21,7 @@ from repro.runtime import (
     get_backend,
     register_backend,
 )
-from repro.serve.engine import generate
+from repro.serve.engine import ServeEngine, generate
 
 
 def _sparse_operand(rng, m, k, bm, bk, density=0.5):
@@ -397,8 +397,9 @@ def _relu_cfg():
 
 
 def test_generate_decode_reuses_prefill_plan():
-    """The LM-head plan is computed once at the (eager) prefill; the jitted
-    decode scan carries it as part of the traced program — ``traced`` counts
+    """The LM-head plan is computed once, eagerly, at the first admission;
+    the jitted prefill and decode programs carry it as part of the traced
+    program — ``traced`` counts
     the single trace, not one plan per token — and a second generation with
     the same runtime cache-hits the prefill plan and retraces nothing."""
     cfg = _relu_cfg()
@@ -436,3 +437,29 @@ def test_generate_matches_dense_under_ambient_sparse_runtime():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out_dense))
     # the second ambient generation replays the first one's prefill plan
     assert rt.plan_cache.misses == 1 and rt.plan_cache.hits >= 1
+
+
+def test_engine_plan_cost_prices_budget_in_head_plan_work():
+    """Under a sparse runtime the work budget is priced in the cached
+    LM-head plan's ``total_work`` (ragged-grid steps) from the first
+    admission on, although the engine's prefill and decode are jitted."""
+    cfg = _relu_cfg()
+    params = init_params(M.param_specs(cfg), jax.random.PRNGKey(0))
+    prompt = jax.random.randint(jax.random.PRNGKey(3), (6,), 0, cfg.vocab_size)
+    rt = Runtime(backend="reference", bm=2, bk=16, bn=16)
+    eng = ServeEngine(params, cfg, slots=2, max_len=16, chunk=2, rt=rt)
+    assert eng._plan_cost() == 1.0  # nothing planned before an admission
+    eng.submit(prompt, max_new=2)
+    eng.run()
+    [ps] = rt.plan_cache.plan_stats()
+    assert ps["key"][0] == "lm_head" and ps["side"] == "B"
+    work = float(ps["total_work"])
+    assert eng._plan_cost() == work > 1.0
+    # a budget of 1.5 requests' grid steps admits one request and sheds the
+    # next; priced in tokens (cost 1.0) neither would be shed
+    eng.work_budget = 1.5 * work * 4
+    first = eng.submit(prompt, max_new=4)
+    second = eng.submit(prompt, max_new=4)
+    reqs = eng._requests
+    assert not reqs[first].finished
+    assert reqs[second].finish_reason == "shed"

@@ -4,7 +4,9 @@
 * the ServeEngine's slot packing must be invisible: every request's tokens
   match a solo single-request generation, whatever shares the batch;
 * the jitted decode program traces once per shape — admission, EOS finish
-  and scheduler backfill never recompile;
+  and scheduler backfill never recompile — and the jitted admission prefill
+  once per (group size, prompt length), matching eager prefill's first
+  tokens for every family;
 * sampling is per-request deterministic (RNG keys are folded per rid and
   split before first use — the PR-2 first-token key-reuse bug stays dead).
 """
@@ -150,6 +152,41 @@ def test_engine_decode_program_traces_once():
         eng.run()
     assert serve_engine.DECODE_TRACES - t0 <= 1
     assert eng.stats()["chunks_run"] >= 3
+
+
+def test_engine_prefill_program_traces_once(monkeypatch):
+    """Admission prefills through one jitted program per (group size, prompt
+    length): waves of same-length single requests trace it once per
+    signature, never once per admission."""
+    cfg, params = _small_setup()
+    rng = np.random.default_rng(4)
+    eng = ServeEngine(params, cfg, slots=2, max_len=24, chunk=2)
+    serve_engine._prefill_group.clear_cache()
+    monkeypatch.setattr(serve_engine, "PREFILL_TRACES", 0)
+    lengths = (4, 6)
+    for wave in range(3):
+        for s in lengths:
+            p = jnp.asarray(rng.integers(0, cfg.vocab_size, (s,)), jnp.int32)
+            eng.submit(p, max_new=2 + wave)
+            eng.run()
+    st = eng.stats()
+    assert serve_engine.PREFILL_TRACES == len(lengths)  # (1, 4) and (1, 6)
+    assert st["prefill_traces"] < st["prefill_groups"] == 3 * len(lengths)
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_engine_first_tokens_match_eager_prefill(arch):
+    """The jitted admission prefill picks the same first tokens as an eager
+    ``M.prefill`` of the same prompts, for every model family."""
+    cfg, params = _small_setup(arch)
+    prompts = jax.random.randint(jax.random.PRNGKey(5), (2, 6), 0, cfg.vocab_size)
+    logits, _ = M.prefill(params, cfg, {"tokens": prompts})
+    want = np.argmax(np.asarray(logits[:, -1], np.float32), axis=-1)
+    eng = ServeEngine(params, cfg, slots=2, max_len=8, chunk=1)
+    rids = [eng.submit(p, max_new=1) for p in prompts]
+    out = eng.run()
+    assert [out[r][0] for r in rids] == want.tolist()
+    assert eng.stats()["prefill_groups"] == 1
 
 
 def test_engine_eos_early_exit_and_backfill():
