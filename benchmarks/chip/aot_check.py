@@ -5,7 +5,9 @@
 
 For a serving cell: the decode chunk at the cell's slots, chunk and
 ``max_len``, and the prefill of the longest prompt at the largest group the
-cell warms.  Each is compiled by the TPU compiler for one chip of a
+cell warms.  For a training cell: the train step at the cell's batch and
+sequence, with its parameters and optimizer state donated, as the run
+builds it.  Each is compiled by the TPU compiler for one chip of a
 ``v5e:2x2`` topology (which refuses what the chip would refuse) and its
 ``memory_analysis()`` and ``tpu_custom_call`` count are printed.  Exits 1
 when a program holds no kernel or does not fit the chip's memory (16 GiB,
@@ -76,6 +78,35 @@ def serve_programs(cell, device):
     yield f"prefill_{g}x{s}", jax.jit(prefill).lower(params, tokens).compile()
 
 
+def train_programs(cell, device):
+    import jax
+    import jax.numpy as jnp
+    from harness import train
+    from repro import runtime as rtm
+    from repro.configs.base import ModelConfig
+    from repro.launch.mesh import make_local_mesh
+    from repro.models import model as M
+    from repro.models.common import abstract_params
+    from repro.optim.adamw import init_opt_state
+    from repro.parallel.sharding import ShardingPolicy
+
+    tr = cell.traffic
+    cfg = ModelConfig(**cell.config["program"])
+    mesh = make_local_mesh(data=True, devices=[device])
+    rt = rtm.Runtime(backend=tr["backend"], sharding=ShardingPolicy(mesh=mesh))
+    one = jax.sharding.SingleDeviceSharding(device)
+    put = lambda t: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), t)
+    params = put(abstract_params(M.param_specs(cfg)))
+    opt = put(jax.eval_shape(init_opt_state, params))
+    tokens = jax.ShapeDtypeStruct((tr["batch"], tr["seq"]), jnp.int32, sharding=one)
+    poison = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+    with mesh, rtm.use(rt):
+        lowered = train.make_step(cfg, tr)[1].lower(
+            params, opt, {"tokens": tokens, "labels": tokens}, poison=poison)
+    yield f"train_step_{tr['batch']}x{tr['seq']}", lowered.compile()
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", action="append")
@@ -98,7 +129,8 @@ def main() -> int:
     ok = True
     for name in names:
         cell = bench.load_cell(name)
-        for prog, compiled in serve_programs(cell, topo.devices[0]):
+        programs = {"serve": serve_programs, "train": train_programs}[cell.traffic["kind"]]
+        for prog, compiled in programs(cell, topo.devices[0]):
             ok &= _report(f"{name}:{prog}", compiled, hbm)
     return 0 if ok else 1
 

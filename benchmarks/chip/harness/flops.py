@@ -6,6 +6,14 @@ a query really attends (causal: a token at position ``t`` sees ``t + 1``),
 not the cache slots a kernel reads.  The LM head of a prefill counts one
 position per request, since only its last logits are used.
 
+A Mamba-2 layer counts its projections, its convolution and the SSD as
+the program computes it, in chunks of ``ssm_chunk`` positions: inside a
+chunk a position pairs with the positions up to it (causal), across chunks
+it reads and feeds the carried state.  A train step counts three times the
+forward operations of its tokens: the forward and the two gradient
+products of each; recomputation does not count.  A family with no count
+here (MoE, hybrid) is refused, not counted as another.
+
 A kernel call's operations count only the nonzero 128x128 blocks of its
 sparse operand (``nonzero_elems``, measured from the data), so a roofline
 share reads the same work whatever implements the product; its bytes are
@@ -47,6 +55,43 @@ def dense_request_ops(p: dict, prompt: int, first: int, last: int) -> float:
     for k in range(first, last):
         ops += layers * dense_layer_ops(p, prompt + k) + head_ops(p)
     return ops
+
+
+def ssm_layer_ops(p: dict, seq: int) -> float:
+    """One token through one Mamba-2 layer of a ``seq``-long sequence."""
+    d, n, hp = p["d_model"], p["ssm_state"], p["ssm_headdim"]
+    di = p["ssm_expand"] * d
+    h = di // hp
+    chunk = p["ssm_chunk"]
+    q = chunk if seq >= chunk and seq % chunk == 0 else seq
+    pairs = (q + 1) / 2  # positions a position sees inside its chunk, on average
+    proj = 2 * d * (2 * di + 2 * n + h) + 2 * di * d  # z, x, B, C, dt; out
+    conv = 2 * p["conv_width"] * (di + 2 * n)
+    ssd = 2 * n * pairs + 2 * di * pairs + 2 * 2 * di * n  # C.B; diagonal blocks; states in and out
+    return float(proj + conv + ssd)
+
+
+def ssm_train_step_ops(p: dict, batch: int, seq: int) -> float:
+    """Model operations of one train step of ``batch`` x ``seq`` tokens."""
+    fwd = p["num_layers"] * ssm_layer_ops(p, seq) + head_ops(p)
+    return 3.0 * batch * seq * fwd
+
+
+def dense_train_step_ops(p: dict, batch: int, seq: int) -> float:
+    """Model operations of one train step of a dense GQA decoder over
+    ``batch`` causal sequences of ``seq`` tokens (keys 1..seq)."""
+    keys = seq * (seq + 1) / 2
+    per_seq = p["num_layers"] * (seq * dense_layer_ops(p, 0)
+                                 + 2 * 2 * p["num_heads"] * p["head_dim"] * keys)
+    return 3.0 * batch * (per_seq + seq * head_ops(p))
+
+
+def train_step_ops(p: dict, batch: int, seq: int) -> float:
+    """Model operations of one train step, by the program's family."""
+    counts = {"ssm": ssm_train_step_ops, "dense": dense_train_step_ops}
+    if p["family"] not in counts:
+        raise ValueError(f"no training count for family {p['family']!r}")
+    return counts[p["family"]](p, batch, seq)
 
 
 def kernel_call(m: int, k: int, n: int, nonzero_elems: float) -> tuple[float, float]:

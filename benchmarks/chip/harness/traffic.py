@@ -15,6 +15,14 @@ of the window.
 * gaps: the quantiles of an exponential distribution of mean
   ``1 / rate_per_s`` (Poisson arrivals), scaled so the last request is due
   inside the window.
+
+A training mix (``"kind": "train"``) is a stream of batches of ``batch``
+sequences of ``seq`` tokens, one batch a step, with next-token labels.  The
+ids follow a Zipf law of exponent ``tokens.zipf_exponent`` over the
+``tokens.vocab`` real ids: rank ``r`` is drawn with weight ``1 / r**a``, and
+which id holds which rank is a permutation drawn from the seed.  Every step
+draws new rows, so a seed changes which tokens come, never the amount of
+work.
 """
 from __future__ import annotations
 
@@ -72,3 +80,25 @@ def serve_requests(traffic: dict, seed: int, seconds: float,
                 int(budgets[i]))
         for i in range(n)
     ]
+
+
+class TrainStream:
+    """The batches of a training mix for one seed: :meth:`batch` of step
+    ``i`` is the same whenever it is asked for."""
+
+    def __init__(self, traffic: dict, seed: int):
+        tok = traffic["tokens"]
+        ranks = np.arange(1, tok["vocab"] + 1, dtype=np.float64)
+        w = ranks ** -float(tok["zipf_exponent"])
+        self.cdf = np.cumsum(w / w.sum())
+        self.ids = np.random.default_rng([seed, 0]).permutation(tok["vocab"]).astype(np.int32)
+        self.shape = (traffic["batch"], traffic["seq"] + 1)
+        self.seed = seed
+
+    def batch(self, step: int) -> dict:
+        """``tokens`` and ``labels`` (the tokens shifted by one), int32
+        ``[batch, seq]``."""
+        u = np.random.default_rng([self.seed, 1, step]).random(self.shape)
+        rank = np.minimum(np.searchsorted(self.cdf, u), len(self.ids) - 1)
+        toks = self.ids[rank]
+        return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}

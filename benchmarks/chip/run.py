@@ -46,9 +46,12 @@ def main(argv=None) -> int:
     except bench.NoChip as e:
         print(f"no chip: {e}", file=sys.stderr)
         return 3
-    if cell.traffic["kind"] != "serve":
+    from harness import serve, train
+
+    drivers = {"serve": serve, "train": train}
+    if cell.traffic["kind"] not in drivers:
         raise SystemExit(f"unknown traffic kind {cell.traffic['kind']!r}")
-    from harness import serve as driver
+    driver = drivers[cell.traffic["kind"]]
     if args.trace:
         import shutil
 

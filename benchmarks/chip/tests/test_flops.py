@@ -45,3 +45,36 @@ def test_qwen3_prefill():
     want = 36 * (128 * 201_850_880 + 16_384 * (128 * 129 // 2)) + 777_912_320
     assert want == 935_776_354_304
     assert flops.dense_request_ops(QWEN, 128, 0, 1) == want
+
+
+MAMBA = bench.load_cell("mamba2-780m.train.2x2048").config["program"]
+
+
+def test_mamba2_layer_token():
+    # d 1536, inner 3072, state 128, 48 heads of 64, conv 4, chunks of 256
+    proj = 2 * 1536 * (2 * 3072 + 2 * 128 + 48) + 2 * 3072 * 1536  # 29245440
+    conv = 2 * 4 * (3072 + 2 * 128)  # 26624
+    ssd = 2 * 128 * 128.5 + 2 * 3072 * 128.5 + 4 * 3072 * 128  # 32896 + 789504 + 1572864
+    assert proj + conv + ssd == 31_667_328
+    assert flops.ssm_layer_ops(MAMBA, 2048) == 31_667_328
+
+
+def test_mamba2_train_step():
+    # 3 x (48 layers + the [1536, 50288] head) x 4096 tokens
+    per_token = 48 * 31_667_328 + 2 * 1536 * 50288
+    assert per_token == 1_674_516_480
+    assert flops.ssm_train_step_ops(MAMBA, 2, 2048) == 3 * 4096 * per_token
+    # a sequence shorter than a chunk is one chunk of its own length
+    assert flops.ssm_layer_ops(MAMBA, 64) == flops.ssm_layer_ops(dict(MAMBA, ssm_chunk=64), 64)
+
+
+def test_train_step_by_family():
+    # qwen3-4b, 2 sequences of 128: 36 layers of 128 positions (keys 1..128),
+    # the head at every position, three times over
+    layers = 36 * (128 * 201_850_880 + 16_384 * (128 * 129 // 2))
+    head = 128 * 777_912_320
+    assert (layers, head) == (934_998_441_984, 99_572_776_960)
+    assert flops.train_step_ops(QWEN, 2, 128) == 6 * (layers + head) == 6_207_427_313_664
+    assert flops.train_step_ops(MAMBA, 2, 2048) == flops.ssm_train_step_ops(MAMBA, 2, 2048)
+    with pytest.raises(ValueError, match="moe"):
+        flops.train_step_ops(dict(QWEN, family="moe"), 2, 128)

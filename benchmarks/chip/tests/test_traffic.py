@@ -65,3 +65,38 @@ def test_every_block_same_work(seed):
         assert sorted(r.max_new for r in blk) == sorted(r.max_new for r in blocks[0])
         np.testing.assert_allclose(np.sort(gaps[i * b:(i + 1) * b]),
                                    np.sort(gaps[:b]), rtol=1e-9)
+
+
+TRAIN = bench.load_cell("mamba2-780m.train.2x2048").traffic
+
+
+def test_train_stream_same_seed_same_batches():
+    a, b = gen.TrainStream(TRAIN, 2**31 + 5), gen.TrainStream(TRAIN, 2**31 + 5)
+    for i in (0, 3, 17):
+        x, y = a.batch(i), b.batch(i)
+        assert all(np.array_equal(x[k], y[k]) for k in ("tokens", "labels"))
+
+
+@pytest.mark.parametrize("seed", [0, 2**31 + 11])
+def test_train_batches_are_next_token_pairs_of_new_rows(seed):
+    s = gen.TrainStream(TRAIN, seed)
+    batches = [s.batch(i) for i in range(4)]
+    for b in batches:
+        assert b["tokens"].shape == b["labels"].shape == (TRAIN["batch"], TRAIN["seq"])
+        assert b["tokens"].dtype == np.int32
+        np.testing.assert_array_equal(b["tokens"][:, 1:], b["labels"][:, :-1])
+        assert 0 <= b["tokens"].min() and b["labels"].max() < TRAIN["tokens"]["vocab"]
+    rows = {r.tobytes() for b in batches for r in b["tokens"]}
+    assert len(rows) == len(batches) * TRAIN["batch"]  # every row differs
+
+
+def test_train_tokens_follow_zipf():
+    s = gen.TrainStream(TRAIN, 7)
+    toks = np.concatenate([s.batch(i)["tokens"].ravel() for i in range(16)])
+    counts = collections.Counter(toks.tolist())
+    # rank 1 against rank 2 of a 1/r law: about twice as often
+    (_, c1), (_, c2) = counts.most_common(2)
+    assert 1.6 < c1 / c2 < 2.5
+    # and the seed decides which id is the most frequent
+    other = collections.Counter(gen.TrainStream(TRAIN, 8).batch(0)["tokens"].ravel().tolist())
+    assert other.most_common(1)[0][0] != counts.most_common(1)[0][0]

@@ -30,3 +30,23 @@ def serve_cell(limits_of: str = "qwen3-4b.serve.saturated", *, layers: int = 2,
     return bench.Cell(name="tiny.serve", chips=1, config=cfg, traffic=tr,
                       end_to_end=[], per_layer=[],
                       limits=copy.deepcopy(bench.load_cell(limits_of).limits))
+
+
+def train_cell(limits_of: str = "mamba2-780m.train.2x2048", *, layers: int = 2,
+               d: int = 64, vocab: int = 500, seq: int = 64, batch: int = 2,
+               backend: str = "interpret") -> bench.Cell:
+    """A tiny mamba2 training cell: 2 layers of width 64, 16-wide state and
+    heads, chunks of 16, a vocabulary of 500 padded to 512."""
+    cfg = _load("configs/mamba2-780m.json")
+    cfg.update(d_model=d, n_layer=layers, vocab_size=vocab,
+               ssm_cfg=dict(cfg["ssm_cfg"], d_state=16, headdim=16, chunk_size=16))
+    padded = -(-vocab // cfg["pad_vocab_size_multiple"]) * cfg["pad_vocab_size_multiple"]
+    cfg["program"] = dict(cfg["program"], name="tiny-ssm", num_layers=layers,
+                          d_model=d, vocab_size=padded, ssm_state=16, ssm_headdim=16,
+                          ssm_chunk=16)
+    tr = _load("traffic/train.2x2048.json")
+    tr.update(batch=batch, seq=seq, backend=backend,
+              tokens=dict(tr["tokens"], vocab=vocab))
+    return bench.Cell(name="tiny.train", chips=1, config=cfg, traffic=tr,
+                      end_to_end=[], per_layer=[],
+                      limits=copy.deepcopy(bench.load_cell(limits_of).limits))
