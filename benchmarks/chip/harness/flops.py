@@ -11,8 +11,14 @@ the program computes it, in chunks of ``ssm_chunk`` positions: inside a
 chunk a position pairs with the positions up to it (causal), across chunks
 it reads and feeds the carried state.  A train step counts three times the
 forward operations of its tokens: the forward and the two gradient
-products of each; recomputation does not count.  A family with no count
-here (MoE, hybrid) is refused, not counted as another.
+products of each; recomputation does not count.
+
+A configuration's plain reference (``reference/<module>.py``) may bring its
+own counts, ``train_step_ops(program, batch, seq)`` and ``request_ops(program,
+prompt, first, last)``, by the same rules; where it does, they are used, and
+otherwise the count of the program's family here.  A family with no count
+here (MoE, hybrid) and none in its reference is refused, not counted as
+another.
 
 A kernel call's operations count only the nonzero 128x128 blocks of its
 sparse operand (``nonzero_elems``, measured from the data), so a roofline
@@ -86,12 +92,28 @@ def dense_train_step_ops(p: dict, batch: int, seq: int) -> float:
     return 3.0 * batch * (per_seq + seq * head_ops(p))
 
 
-def train_step_ops(p: dict, batch: int, seq: int) -> float:
-    """Model operations of one train step, by the program's family."""
+def train_step_ops(p: dict, batch: int, seq: int, ref=None) -> float:
+    """Model operations of one train step: the reference module ``ref``'s
+    own count where it has one, else the program's family's."""
+    count = getattr(ref, "train_step_ops", None)
+    if count is not None:
+        return float(count(p, batch, seq))
     counts = {"ssm": ssm_train_step_ops, "dense": dense_train_step_ops}
     if p["family"] not in counts:
         raise ValueError(f"no training count for family {p['family']!r}")
     return counts[p["family"]](p, batch, seq)
+
+
+def request_ops(p: dict, prompt: int, first: int, last: int, ref=None) -> float:
+    """Operations of output tokens ``first..last-1`` of a request
+    (:func:`dense_request_ops`): the reference module ``ref``'s own count
+    where it has one, else the program's family's."""
+    count = getattr(ref, "request_ops", None)
+    if count is not None:
+        return float(count(p, prompt, first, last))
+    if p["family"] not in ("dense", "moe"):
+        raise ValueError(f"no serving count for family {p['family']!r}")
+    return dense_request_ops(p, prompt, first, last)
 
 
 def kernel_call(m: int, k: int, n: int, nonzero_elems: float) -> tuple[float, float]:

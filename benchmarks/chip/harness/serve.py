@@ -89,15 +89,10 @@ def warm(eng, tr: dict, vocab: int) -> None:
         eng.step()
 
 
-def _request_ops(program: dict, prompt: int, first: int, last: int) -> float:
-    if program["family"] in ("dense", "moe"):
-        return flops.dense_request_ops(program, prompt, first, last)
-    raise ValueError(f"no serving count for family {program['family']!r}")
-
-
-def window(eng, reqs, seconds: float, tr: dict, counter, traced: bool):
+def window(eng, reqs, seconds: float, tr: dict, counter, traced: bool, ref=None):
     """Run the open loop for ``seconds``; returns the window's records and
-    ``{request index: engine rid}``."""
+    ``{request index: engine rid}``.  ``ref``, the configuration's reference
+    module, may bring the operation count (``flops.request_ops``)."""
     import jax
 
     program = eng_program(eng)
@@ -148,7 +143,7 @@ def window(eng, reqs, seconds: float, tr: dict, counter, traced: bool):
         for r, old in zip(live, lens):
             new = len(r.tokens)
             if new > old:
-                ops += _request_ops(program, int(r.prompt.shape[0]), old, new)
+                ops += flops.request_ops(program, int(r.prompt.shape[0]), old, new, ref)
                 first_tokens += old == 0
             if not r.finished:
                 still.append(r)
@@ -201,7 +196,8 @@ def run(cell, seed: int, seconds: float, traced: bool, t0: float, devices,
     jax.block_until_ready(eng.caches)
     setup_s = time.monotonic() - t0
 
-    rec, rids = window(eng, reqs, seconds, tr, counter, traced)
+    rec, rids = window(eng, reqs, seconds, tr, counter, traced,
+                       reference.load_module(cell.config["reference"]["module"]))
     counter.report()
     t_close = drain(eng, rids, tr["drain_seconds"])
     done = [eng._requests[rids[r.index]] for r in reqs]

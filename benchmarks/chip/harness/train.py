@@ -36,11 +36,12 @@ NUMBERS = ("loss_gap", "first_grad_gap", "change_gap", "grad_dir_median")
 class TrainRun:
     """What one training run hands the per-layer metric readers."""
 
-    peak: dict
+    peak: dict  # one chip's
     window_s: float
     ops: float  # model operations of the window's steps (flops.train_step_ops)
     compiles: int  # executables built inside the window
     head: dict  # the LM head's k, n, tokens per step and nonzero fraction
+    chips: int = 1  # the chips the step runs on, each with its share of the rows
     trace: "trace_mod.Reduction | None" = None
     kernel_pattern: str = ""
     kind: str = "train"
@@ -340,7 +341,9 @@ def run(cell, seed: int, seconds: float, traced: bool, t0: float, devices,
     counter.report()
     device = bench.device_record(devices)
     tokens = rec["steps"] * tokens_per_step
-    ops = rec["steps"] * flops.train_step_ops(config["program"], tr["batch"], tr["seq"])
+    ops = rec["steps"] * flops.train_step_ops(
+        config["program"], tr["batch"], tr["seq"],
+        reference.load_module(config["reference"]["module"]))
 
     metrics = {}
     if traced:
@@ -349,7 +352,8 @@ def run(cell, seed: int, seconds: float, traced: bool, t0: float, devices,
         device["busy_s"] = red.busy_s
         device["window_s"] = red.window_s
         run_ = TrainRun(peak=bench.load_peaks(device["kind"]), window_s=rec["window_s"],
-                        ops=ops, compiles=rec["compiles"], head=head, trace=red,
+                        ops=ops, compiles=rec["compiles"], head=head, chips=len(devices),
+                        trace=red,
                         kernel_pattern=config["kernels"]["head"])
         metrics = bench.read_per_layer(cell, run_)
         breakdown = {"device_ops": red.top_ops(), "idle_gaps": red.top_idle()}

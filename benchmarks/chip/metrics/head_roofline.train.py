@@ -1,15 +1,17 @@
 """Roofline share of the planned kernel on the LM head's three training
 products: the forward ``[T, d] x [d, V]`` (computed transposed, its result
 ``[V, T]``), the input gradient ``[T, V] x [V, d]`` and the weight gradient
-``[d, T] x [T, V]``, with ``T`` the step's tokens.  Each kernel event in
-the traced window is priced at its own ``(m, k, n)``: its 2-D result shape
+``[d, T] x [T, V]``, with ``T`` the tokens of one chip's share of the
+step (the step's tokens over the chips: on several chips each runs the
+kernel on its own rows against the whole head).  Each kernel event of every
+chip in the traced window is priced at its own ``(m, k, n)``: its 2-D result shape
 names two of ``T``, ``d`` and ``V`` (a dimension padded up to a multiple of
 128 counts at its own size) and the third is contracted.  Its least time is
 the larger of its operations (on the head's nonzero blocks) over the
 chip's peak and its bytes (each operand read once, the result written once,
 bf16) over the bandwidth; the share is the sum of least times over the sum
-of the events' device time.  At 4096 tokens every product is bound by
-compute."""
+of the events' device time.  At 4096 tokens (mamba2, one chip) every
+product is bound by compute."""
 from harness import flops, kernels
 
 
@@ -26,7 +28,7 @@ def read(run):
     events = run.trace.kernel_events(run.kernel_pattern)
     if not events:
         return None
-    sizes = {"T": run.head["m"], "d": run.head["k"], "V": run.head["n"]}
+    sizes = {"T": run.head["m"] // run.chips, "d": run.head["k"], "V": run.head["n"]}
     least = 0.0
     for e in events:
         out = [_size(x, sizes) for x in kernels.result_shape(e)]

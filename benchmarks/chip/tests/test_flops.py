@@ -78,3 +78,26 @@ def test_train_step_by_family():
     assert flops.train_step_ops(MAMBA, 2, 2048) == flops.ssm_train_step_ops(MAMBA, 2, 2048)
     with pytest.raises(ValueError, match="moe"):
         flops.train_step_ops(dict(QWEN, family="moe"), 2, 128)
+
+
+def test_a_reference_module_brings_its_own_count():
+    import types
+
+    from harness import reference
+
+    own = types.SimpleNamespace(
+        train_step_ops=lambda p, batch, seq: 7.0 * batch * seq,
+        request_ops=lambda p, prompt, first, last: 11.0 * (last - first))
+    hybrid = dict(QWEN, family="hybrid")
+    assert flops.train_step_ops(hybrid, 2, 128, own) == 7.0 * 256
+    assert flops.request_ops(hybrid, 128, 0, 3, own) == 33.0
+    with pytest.raises(ValueError, match="hybrid"):
+        flops.request_ops(hybrid, 128, 0, 3)
+    # the benchmark's references bring none: the family's counts, as before
+    for name, p in (("qwen3", QWEN), ("mamba2", MAMBA)):
+        ref = reference.load_module(name)
+        assert flops.train_step_ops(p, 2, 2048, ref) == flops.train_step_ops(p, 2, 2048)
+    ref = reference.load_module("qwen3")
+    assert flops.request_ops(QWEN, 128, 0, 1, ref) == 935_776_354_304
+    assert flops.request_ops(QWEN, 128, 1, 2, ref) == 8_120_631_296
+    assert flops.train_step_ops(QWEN, 2, 128, ref) == 6_207_427_313_664
